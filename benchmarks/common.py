@@ -13,6 +13,10 @@ import numpy as np
 
 from repro.data.synthetic import ClassifyConfig, batched, classify_dataset
 from repro.models.cnn import cnn_accuracy, cnn_loss, init_cnn
+from repro.utils.compile_cache import use_compile_cache
+
+# every benchmark script imports this module before its first compile
+use_compile_cache()
 
 # every emit()/emit_json() lands here so a bench module can snapshot
 # its own metrics for the trajectory file without re-plumbing returns

@@ -32,18 +32,67 @@ from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 from repro.analysis.findings import Finding
 
-# primitives that move control or data to the host mid-graph
-_CALLBACK_PRIMS = {"pure_callback", "io_callback", "debug_callback",
-                   "callback", "infeed", "outfeed"}
-# cross-device reductions whose operand must be exactness-safe
-# (psum2 is the name the shard_map check_rep rewrite gives psum)
-_REDUCE_PRIMS = {"psum", "psum2", "psum_scatter", "all_reduce"}
+# device<->host transfers with no public tracing API
+_HOST_FEED_PRIMS = {"infeed", "outfeed"}
 # structural ops a zeros-rooted buffer may pass through untouched
-# (pbroadcast is the value-preserving replication marker the shard_map
-# check_rep rewrite inserts)
-_TRANSPARENT_PRIMS = {"reshape", "squeeze", "transpose", "broadcast_in_dim",
-                      "convert_element_type", "copy", "sharding_constraint",
-                      "pbroadcast"}
+_STRUCTURAL_PRIMS = {"reshape", "squeeze", "transpose", "broadcast_in_dim",
+                     "convert_element_type", "copy", "sharding_constraint"}
+
+
+def _output_prim(closed) -> str:
+    """Name of the primitive producing a traced function's output,
+    looking through the shard_map wrapper."""
+    jx = closed.jaxpr
+    while True:
+        eqn = jx.eqns[-1]
+        subs = [s for v in eqn.params.values() for s in _sub_jaxprs(v)]
+        if not subs:
+            return eqn.primitive.name
+        jx = subs[0]
+
+
+@functools.lru_cache(maxsize=None)
+def _api_prims() -> Tuple[frozenset, frozenset, frozenset]:
+    """(host callbacks, cross-device sums, value-preserving markers) as
+    the installed JAX names them, found by tracing the public API that
+    does each thing — so a release that renames a primitive (JAX 0.9:
+    ``debug_print``, ``psum_invariant``, ``pvary``) is still matched by
+    what it does. Tracing is abstract: no device is touched."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import io_callback
+    from jax.sharding import AbstractMesh, PartitionSpec as P
+
+    x = jax.ShapeDtypeStruct((4,), jnp.float32)
+    out = jax.ShapeDtypeStruct((4,), jnp.float32)
+    callbacks = (
+        lambda a: (jax.debug.print("{}", a), a)[1],
+        lambda a: (jax.debug.callback(lambda _: None, a), a)[1],
+        lambda a: jax.pure_callback(lambda b: b, out, a),
+        lambda a: io_callback(lambda b: b, out, a),
+    )
+    # each trace is the callback primitive alone
+    host = {e.primitive.name for f in callbacks
+            for e in jax.make_jaxpr(f)(x).eqns}
+
+    mesh = AbstractMesh((1,), ("i",))
+    reduce_, marker = set(), set()
+    for check_vma in (True, False):
+        def smap(body, out_spec=P()):
+            return jax.make_jaxpr(jax.shard_map(
+                body, mesh=mesh, in_specs=P("i"), out_specs=out_spec,
+                check_vma=check_vma))(x)
+        # rpr-ok: RPR002 traced abstractly only, to learn the primitive's name
+        reduce_.add(_output_prim(smap(lambda a: jax.lax.psum(a, "i"))))
+        reduce_.add(_output_prim(smap(
+            # rpr-ok: RPR002 traced abstractly only, to learn the primitive's name
+            lambda a: jax.lax.psum_scatter(a, "i", tiled=True), P("i"))))
+        if check_vma:
+            marker.add(_output_prim(smap(
+                lambda a: jax.lax.pcast(jnp.zeros(a.shape, a.dtype), "i",
+                                        to="varying"), P("i"))))
+    return (frozenset(host | _HOST_FEED_PRIMS), frozenset(reduce_),
+            frozenset(marker))
 
 
 # ---------------------------------------------------------------------------
@@ -111,7 +160,7 @@ def _zero_rooted(var, producers: Dict, depth: int = 0) -> bool:
     if name in ("broadcast_in_dim", "fill"):
         return _is_literal_zero(eqn.invars[0]) or \
             _zero_rooted(eqn.invars[0], producers, depth + 1)
-    if name in _TRANSPARENT_PRIMS:
+    if name in _STRUCTURAL_PRIMS or name in _api_prims()[2]:
         return _zero_rooted(eqn.invars[0], producers, depth + 1)
     if name in ("mul",):                  # 0 * x == 0 (finite int grids)
         return any(_zero_rooted(v, producers, depth + 1)
@@ -141,6 +190,7 @@ def check_closed_jaxpr(closed, target: str, hot: bool = False
         dt = _dtype_of(var)
         return dt is not None and dt == np.dtype("float64")
 
+    callback_prims, reduce_prims, _ = _api_prims()
     top = closed.jaxpr
     for var in top.invars:
         if is_f64(var) and not seen_f64:
@@ -174,12 +224,12 @@ def check_closed_jaxpr(closed, target: str, hot: bool = False
                         "accumulator truncated before the scale fold "
                         "(int32 must widen to fp32; fold first, downcast "
                         "after)"))
-        if hot and (name in _CALLBACK_PRIMS or "callback" in name):
+        if hot and (name in callback_prims or "callback" in name):
             findings.append(Finding(
                 "RPR103", "error", target,
                 f"host callback `{name}` in the decode hot path — every "
                 "burst step would synchronize device -> host"))
-        if name in _REDUCE_PRIMS:
+        if name in reduce_prims:
             for v in eqn.invars:
                 dt = _dtype_of(v)
                 if dt is None:
@@ -233,7 +283,7 @@ def _kernel_targets() -> List[TraceTarget]:
 
     def paged_jaxpr():
         q = jnp.zeros((2, 1, 4, 16), jnp.float32)
-        kp = jnp.zeros((6, 4, 2, 16), jnp.float32)    # (P, page, KV, Dh)
+        kp = jnp.zeros((6, 2, 4, 16), jnp.float32)    # (P, KV, page, Dh)
         table = jnp.zeros((2, 3), jnp.int32)
         pos = jnp.zeros((2,), jnp.int32)
         return jax.make_jaxpr(

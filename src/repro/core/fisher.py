@@ -76,7 +76,20 @@ def ef_trace_weights(
         return _ef_trace_weights_sharded(loss_fn, params, batch, mesh,
                                          mesh_axis, microbatch)
     n = jax.tree_util.tree_leaves(batch)[0].shape[0]
-    mb = microbatch or n
+    return _mean_traces(_sqnorm_program(loss_fn, n, microbatch or n)(
+        params, batch))
+
+
+def _mean_traces(sq: Dict[str, jnp.ndarray]) -> Dict[str, float]:
+    return {k: float(jnp.mean(v)) for k, v in sq.items()}
+
+
+def _sqnorm_program(loss_fn: LossFn, n: int, mb: int) -> Callable:
+    """Jitted ``(params, batch) -> {block: per-sample squared norms}``
+    over a batch of ``n`` samples in microbatches of ``mb``. ``params``
+    is an argument of the program — closed over, a model's weights would
+    be baked into the program as constants (gigabytes at LLM scale) —
+    so one program serves every batch of a stream."""
     assert n % mb == 0, f"batch {n} not divisible by microbatch {mb}"
 
     def single_loss(p, z):
@@ -85,17 +98,18 @@ def ef_trace_weights(
 
     per_sample_grad = jax.vmap(jax.grad(single_loss), in_axes=(None, 0))
 
-    def chunk_sqnorms(z_chunk):
-        g = per_sample_grad(params, z_chunk)
-        return _block_sqnorms(g)
+    def chunk_sqnorms(p, z_chunk):
+        return _block_sqnorms(per_sample_grad(p, z_chunk))
 
     if mb == n:
-        sq = jax.jit(chunk_sqnorms)(batch)
-        return {k: float(jnp.mean(v)) for k, v in sq.items()}
+        return jax.jit(chunk_sqnorms)
 
-    chunks = jax.tree.map(lambda a: a.reshape(n // mb, mb, *a.shape[1:]), batch)
-    sq = jax.jit(lambda c: jax.lax.map(chunk_sqnorms, c))(chunks)
-    return {k: float(jnp.mean(v)) for k, v in sq.items()}
+    def mapped(p, batch):
+        chunks = jax.tree.map(
+            lambda a: a.reshape(n // mb, mb, *a.shape[1:]), batch)
+        return jax.lax.map(lambda c: chunk_sqnorms(p, c), chunks)
+
+    return jax.jit(mapped)
 
 
 def _ef_trace_weights_sharded(
@@ -107,7 +121,7 @@ def _ef_trace_weights_sharded(
     microbatch: Optional[int],
 ) -> Dict[str, float]:
     """Data-parallel EF trace: shard the batch, psum per-block sums."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = jax.tree_util.tree_leaves(batch)[0].shape[0]
@@ -139,12 +153,12 @@ def _ef_trace_weights_sharded(
         # rpr-ok: RPR002 fp32 Fisher-trace statistics — an estimator (Prop. 5 Monte-Carlo), not a bit-exactness surface; summation order is part of its noise floor
         return jax.lax.psum(sums, mesh_axis)
 
-    # check_rep=False: pallas_call (the ef_sqnorm kernel in interpret
+    # check_vma=False: pallas_call (the ef_sqnorm kernel in interpret
     # mode) has no replication rule; we psum explicitly so the check is
     # redundant here.
     f = jax.jit(shard_map(local_fn, mesh=mesh,
                           in_specs=(P(), P(mesh_axis)), out_specs=P(),
-                          check_rep=False))
+                          check_vma=False))
     sums = f(params, batch)
     return {k: float(v) / n for k, v in sums.items()}
 
@@ -170,9 +184,17 @@ def ef_trace_weights_streaming(
     sums: Dict[str, float] = {}
     totals: list[float] = []
     count = 0
+    program, program_n = None, None
     for batch in batches:
-        t = ef_trace_weights(loss_fn, params, batch, microbatch,
-                             mesh=mesh, mesh_axis=mesh_axis)
+        n = jax.tree_util.tree_leaves(batch)[0].shape[0]
+        if mesh is not None and int(mesh.shape[mesh_axis]) > 1:
+            t = ef_trace_weights(loss_fn, params, batch, microbatch,
+                                 mesh=mesh, mesh_axis=mesh_axis)
+        else:
+            if n != program_n:       # one compiled program per batch shape
+                program = _sqnorm_program(loss_fn, n, microbatch or n)
+                program_n = n
+            t = _mean_traces(program(params, batch))
         count += 1
         for k, v in t.items():
             sums[k] = sums.get(k, 0.0) + v

@@ -15,15 +15,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK = (512, 1024)
 
 
 def _fq_kernel(x_ref, scale_ref, zp_ref, o_ref, *, levels: float):
     x = x_ref[...]
-    scale = scale_ref[0, 0]
+    scale = scale_ref[0, 0]                    # SMEM scalars
     zp = zp_ref[0, 0]
-    inv = pl.reciprocal(scale, approx=False) if hasattr(pl, "reciprocal") else 1.0 / scale
+    inv = 1.0 / scale
     q = jnp.round(x.astype(jnp.float32) * inv + zp)
     q = jnp.clip(q, 0.0, levels)
     o_ref[...] = ((q - zp) * scale).astype(o_ref.dtype)
@@ -58,8 +59,8 @@ def fake_quant_pallas(x: jnp.ndarray, scale: jnp.ndarray, zero_point: jnp.ndarra
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), x.dtype),

@@ -111,7 +111,7 @@ def _grouped_qmm_kernel(cnt_ref, eid_ref, x_ref, w_ref, ws_ref, xs_ref,
             x_ref[0], w, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32,
         )
-        acc_ref[...] += prod.astype(jnp.float32) * ws_ref[0]
+        acc_ref[...] += prod.astype(jnp.float32) * ws_ref[0, 0]
 
     @pl.when(g == n_groups - 1)
     def _finalize():
@@ -165,8 +165,10 @@ def grouped_qmm_pallas(x_q: jnp.ndarray, w_data: jnp.ndarray,
             # payload/scale rows, selected at block-fetch time
             pl.BlockSpec((1, bkp, bn),
                          lambda s, i, j, g, cnt, eid: (eid[s], g, j)),
-            pl.BlockSpec((1, 1, bn),
-                         lambda s, i, j, g, cnt, eid: (eid[s], g, j)),
+            # scales ride as (E, G, 1, N): a (1, 1, 1, bn) block keeps its
+            # last two dims (full, lane-aligned) — the TPU block-shape rule
+            pl.BlockSpec((1, 1, 1, bn),
+                         lambda s, i, j, g, cnt, eid: (eid[s], g, 0, j)),
             pl.BlockSpec((1, bm, 1),
                          lambda s, i, j, g, cnt, eid: (s, i, 0)),
         ],
@@ -181,5 +183,5 @@ def grouped_qmm_pallas(x_q: jnp.ndarray, w_data: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((s, c2, n2), out_dtype),
         interpret=interpret,
     )(counts.astype(jnp.int32), expert_ids.astype(jnp.int32),
-      x_q, w_data, w_scale.astype(jnp.float32), x_scale)
+      x_q, w_data, w_scale.astype(jnp.float32)[:, :, None, :], x_scale)
     return out[:, :c, :n]

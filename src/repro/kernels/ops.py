@@ -204,7 +204,7 @@ def paged_attention(q, k_pages, v_pages, table, pos, k_scale=None,
     if mode != "tpu":
         return _ref.paged_attention(q, k_pages, v_pages, table, pos,
                                     k_scale, v_scale, bits)
-    kvh = k_pages.shape[2]
+    kvh = k_pages.shape[1]
     b, _, h, dh = q.shape
     qh = q.reshape(b, kvh, h // kvh, dh)
     return paged_attention_pallas(qh, k_pages, v_pages, table, pos + 1,

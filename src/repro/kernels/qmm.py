@@ -72,6 +72,12 @@ def _validate(name: str, x_q, w_data, w_scale, bits: int, k: int) -> int:
     return n_groups
 
 
+def _scale_rows(w_scale):
+    """(G, N) group scales -> (G, 1, N) fp32: one group's scale row is
+    then a (1, 1, bn) block whose last two dims are legal TPU tiles."""
+    return w_scale.astype(jnp.float32)[:, None, :]
+
+
 def _qmm_kernel(x_ref, w_ref, ws_ref, xs_ref, o_ref, acc_ref,
                 *, n_groups: int, bits: int):
     g = pl.program_id(2)
@@ -89,7 +95,7 @@ def _qmm_kernel(x_ref, w_ref, ws_ref, xs_ref, o_ref, acc_ref,
     )
     # fused grouped dequant: this group's exact int32 dot scaled into the
     # fp32 accumulator by its per-channel scales
-    acc_ref[...] += prod.astype(jnp.float32) * ws_ref[...]
+    acc_ref[...] += prod.astype(jnp.float32) * ws_ref[0]
 
     @pl.when(g == n_groups - 1)
     def _finalize():
@@ -104,7 +110,7 @@ def _qmm_groups_kernel(x_ref, w_ref, ws_ref, o_ref, *, bits: int):
         x_ref[...], w, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
     )
-    o_ref[0] = prod.astype(jnp.float32) * ws_ref[...]
+    o_ref[0] = prod.astype(jnp.float32) * ws_ref[0]
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "k", "bm", "bn",
@@ -139,12 +145,12 @@ def qmm_groups_pallas(x_q: jnp.ndarray, w_data: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, g: (i, g)),
             pl.BlockSpec((bkp, bn), lambda i, j, g: (g, j)),
-            pl.BlockSpec((1, bn), lambda i, j, g: (g, j)),
+            pl.BlockSpec((1, 1, bn), lambda i, j, g: (g, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda i, j, g: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((n_groups, m2, n2), jnp.float32),
         interpret=interpret,
-    )(x_q, w_data, w_scale.astype(jnp.float32))
+    )(x_q, w_data, _scale_rows(w_scale))
     return out[:, :m, :n]
 
 
@@ -184,14 +190,14 @@ def qmm_pallas(x_q: jnp.ndarray, w_data: jnp.ndarray, x_scale: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, g: (i, g)),
             pl.BlockSpec((bkp, bn), lambda i, j, g: (g, j)),
-            pl.BlockSpec((1, bn), lambda i, j, g: (g, j)),
+            pl.BlockSpec((1, 1, bn), lambda i, j, g: (g, 0, j)),
             pl.BlockSpec((bm, 1), lambda i, j, g: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, g: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m2, n2), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x_q, w_data, w_scale.astype(jnp.float32), x_scale.reshape(m2, 1))
+    )(x_q, w_data, _scale_rows(w_scale), x_scale.reshape(m2, 1))
     return out[:m, :n]
 
 

@@ -172,7 +172,7 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     """Decode-time GQA over a paged KV pool — the jnp oracle.
 
     q: (B, 1, H, Dh) current-token queries (post-RoPE);
-    k_pages/v_pages: (P, page, KV, Dh') — int8 or packed uint8 on the
+    k_pages/v_pages: (P, KV, page, Dh') — int8 or packed uint8 on the
     ``repro.qtensor`` byte layout when ``bits`` < 16 (Dh' =
     packed_size(Dh, bits)), else a float dtype;
     table: (B, NP) page ids per slot (entries >= P are padding);
@@ -187,11 +187,10 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     change here in ``repro.models.attention.attention_decode``.
     """
     b = q.shape[0]
-    num_pages, page = k_pages.shape[0], k_pages.shape[1]
-    kvh = k_pages.shape[2]
+    num_pages, kvh, page = k_pages.shape[:3]
     ids = jnp.clip(table, 0, num_pages - 1)
-    kg = k_pages[ids]                      # (B, NP, page, KV, Dh')
-    vg = v_pages[ids]
+    kg = k_pages[ids].transpose(0, 1, 3, 2, 4)     # (B, NP, page, KV, Dh')
+    vg = v_pages[ids].transpose(0, 1, 3, 2, 4)
     if bits < 16:
         from repro import qtensor as _qt
         kg, vg = _qt.unpack(kg, bits), _qt.unpack(vg, bits)

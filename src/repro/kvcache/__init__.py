@@ -6,7 +6,7 @@ pages plus per-slot page tables:
 
             physical page pool (per attention layer)
             ┌────┬────┬────┬────┬────┬────┬────┬────┐
-    k/v     │ p0 │ p1 │ p2 │ p3 │ p4 │ p5 │ p6 │ …  │  (P, page, KV, Dh)
+    k/v     │ p0 │ p1 │ p2 │ p3 │ p4 │ p5 │ p6 │ …  │  (P, KV, page, Dh)
             └────┴────┴────┴────┴────┴────┴────┴────┘
               ▲     ▲     ▲           ▲     ▲
     slot 0:  [p0,   p1,   p2,  ·  ]   │     │   table (S, NP) int32

@@ -1,7 +1,9 @@
 """Device-side paged KV storage: per-layer page pools + page-table state.
 
 Layout (see the package docstring for the page-table diagram): each
-attention layer owns a ``(P, page, KV, Dh')`` pool for k and v. Layers
+attention layer owns a kv-head-major ``(P, KV, page, Dh')`` pool for k
+and v — one page of one head is a contiguous ``(page, Dh')`` tile, the
+block the Pallas paged-attention kernel DMAs. Layers
 are kept as a dict (not stacked on a leading axis) so every layer can
 store at its OWN bit width — the FIT-allocated mixed-precision KV cache
 stores an 8-bit layer as int8 bytes and sub-byte layers as packed uint8
@@ -14,7 +16,7 @@ Pages speak the framework-wide QTensor convention: packing/unpacking and
 the symmetric grid come from ``repro.qtensor`` — the SAME byte layout
 and ±(2^(b-1)−1) grid the weight path packs — with per-page per-kv-head
 scales stored as ``(P, KV)`` fp32 alongside each pool (a grouped QTensor
-scale of shape (P, 1, KV, 1); ``LayerPages.k_qt`` exposes the view).
+scale of shape (P, KV, 1, 1); ``LayerPages.k_qt`` exposes the view).
 Scales are materialized from the sensitivity report's calibrated
 activation ranges (``repro.core.report.act_ranges`` at the ``attn/k`` /
 ``attn/v`` tap sites) — the AIMET-style calibrated-range pattern — with
@@ -62,7 +64,7 @@ class LayerPages:
     QTensor convention (pack axis = Dh, per-page per-kv-head scale
     groups); ``k_qt``/``v_qt`` expose the pool as actual QTensors."""
 
-    k: jnp.ndarray          # (P, page, KV, Dh) fp/int8 | (P, page, KV, Dh') uint8
+    k: jnp.ndarray          # (P, KV, page, Dh) fp/int8 | (P, KV, page, Dh') uint8
     v: jnp.ndarray
     k_scale: jnp.ndarray    # (P, KV) fp32 per-page per-kv-head dequant scale
     v_scale: jnp.ndarray
@@ -81,17 +83,17 @@ class LayerPages:
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[1]
+        return self.k.shape[2]
 
     def _logical_shape(self) -> Tuple[int, ...]:
-        p, page, kv, hd = self.k.shape
+        p, kv, page, hd = self.k.shape
         if self.bits < 16:
             hd = logical_size(hd, self.bits)
-        return (p, page, kv, hd)
+        return (p, kv, page, hd)
 
     def _as_qtensor(self, data: jnp.ndarray, scale: jnp.ndarray) -> QTensor:
-        p, _, kv, _ = data.shape[:4]
-        return QTensor(data, scale.reshape(p, 1, kv, 1), self.bits,
+        p, kv = data.shape[:2]
+        return QTensor(data, scale.reshape(p, kv, 1, 1), self.bits,
                        self._logical_shape(), 3)
 
     @property
@@ -184,7 +186,7 @@ def init_paged_kv(cfg: ModelConfig, pcfg: PagedKVConfig, slots: int,
             dtype, last = jnp.uint8, packed_size(hd, bits)
         else:
             dtype, last = jnp.int8, hd          # grid-reduced int8 (7, 5, 8)
-        shape = (pcfg.num_pages, pcfg.page_size, kv, last)
+        shape = (pcfg.num_pages, kv, pcfg.page_size, last)
         ksite, vsite = kv_sites_for_layer(cfg, i)
         layers[str(i)] = LayerPages(
             k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
@@ -221,7 +223,8 @@ def gather_layer(lp: LayerPages, row: jnp.ndarray, n_tokens,
     ``n_tokens`` (the prefix-reuse read: seeds a dense scratch state so
     suffix prefill attends to a shared prefix without recomputing it)."""
     ids = jnp.clip(row, 0, lp.num_pages - 1)
-    kg, vg = lp.k[ids], lp.v[ids]                  # (NP, page, KV, Dh')
+    kg = lp.k[ids].transpose(0, 2, 1, 3)           # (NP, page, KV, Dh')
+    vg = lp.v[ids].transpose(0, 2, 1, 3)
     if lp.bits < 16:
         kg = dequantize_kv(kg, lp.k_scale[ids][:, None, :], lp.bits)
         vg = dequantize_kv(vg, lp.v_scale[ids][:, None, :], lp.bits)
@@ -251,8 +254,8 @@ def scatter_span(lp: LayerPages, row: jnp.ndarray, k_span: jnp.ndarray,
         kq, vq = k_span.astype(lp.k.dtype), v_span.astype(lp.v.dtype)
     return dataclasses.replace(
         lp,
-        k=lp.k.at[pids, offs].set(kq, mode="drop"),
-        v=lp.v.at[pids, offs].set(vq, mode="drop"))
+        k=lp.k.at[pids, :, offs].set(kq, mode="drop"),
+        v=lp.v.at[pids, :, offs].set(vq, mode="drop"))
 
 
 def copy_page(lp: LayerPages, src, dst) -> LayerPages:

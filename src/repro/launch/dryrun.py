@@ -41,7 +41,7 @@ from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import RooflineTerms, model_flops, param_counts
 from repro.launch.sharding import ShardOptions
 from repro.launch.steps import build_step
-from repro.utils.hlo import CollectiveStats, collective_bytes, cost_analysis_dict
+from repro.utils.hlo import CollectiveStats, collective_bytes
 from repro.utils.logging import get_logger
 
 log = get_logger("repro.dryrun")
@@ -81,7 +81,7 @@ def _lower(cfg: ModelConfig, shape: ShapeSpec, mesh, opts: ShardOptions):
 
 def _analyze(lowered, f32_as_bf16: bool = True) -> Dict:
     compiled = lowered.compile()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes(hlo, f32_as_bf16=f32_as_bf16)

@@ -4,32 +4,18 @@
 touches jax device state): (16, 16) ("data", "model") single pod — 256
 chips — or (2, 16, 16) ("pod", "data", "model") for the 2-pod / 512-chip
 dry run. The "pod" axis is an outer data-parallel axis whose collectives
-cross the inter-pod DCN links.
-
-``jax.sharding.AxisType`` only exists in newer JAX releases; on older
-ones (this container ships 0.4.x) ``make_mesh`` falls back to a plain
-``Mesh`` over a device grid — semantically identical for every use in
-this repo (all axes are Auto).
+cross the inter-pod DCN links. Every axis is ``AxisType.Auto``.
 """
 from __future__ import annotations
 
 from typing import Tuple
 
-import numpy as np
-
 import jax
-
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> jax.sharding.Mesh:
-    if _HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    devices = np.asarray(jax.devices()[: int(np.prod(shape))]).reshape(shape)
-    return jax.sharding.Mesh(devices, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -19,7 +19,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -73,7 +73,7 @@ def pipeline_apply(layer_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
         body, mesh=mesh,
         in_specs=(P(axis), P()),      # params sharded by stage; data replicated
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return mapped(stage_params, x_micro)
 

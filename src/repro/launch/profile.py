@@ -41,6 +41,7 @@ from repro.quant.policy import QuantPolicy
 from repro.serve import (
     Engine, EngineConfig, bit_config_from_report, poisson_requests,
     quantize_params)
+from repro.utils.compile_cache import use_compile_cache
 from repro.utils.logging import get_logger
 
 log = get_logger("repro.launch.profile")
@@ -163,6 +164,7 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="schema-versioned profile payload")
     a = ap.parse_args()
+    use_compile_cache()
     profile(arch=a.arch, smoke=a.smoke, batch=a.batch,
             prompt_len=a.prompt_len, gen_len=a.gen_len,
             n_requests=a.requests, rate=a.rate, weight_bits=a.weight_bits,

@@ -69,6 +69,7 @@ from repro.quant.quantizer import QuantSpec, fake_quant_ref
 from repro.serve import (
     Engine, EngineConfig, SamplingParams, poisson_requests, quantize_params,
     quantize_params_int8, trace_requests, weight_storage_bytes)
+from repro.utils.compile_cache import use_compile_cache
 from repro.utils.logging import get_logger
 from repro.utils.pytree import map_with_names
 
@@ -405,6 +406,7 @@ def main() -> None:
     ap.add_argument("--drift-threshold", type=float, default=1.5,
                     help="activation-range ratio that flags a site")
     args = ap.parse_args()
+    use_compile_cache()
 
     out = serve(args.arch, args.smoke, args.batch, args.prompt_len,
                 args.gen_len, args.weight_bits, seed=args.seed,

@@ -27,6 +27,7 @@ from repro.launch.sharding import ShardOptions
 from repro.launch.steps import TrainState, build_train_step, uniform_levels
 from repro.models import init_params
 from repro.optim.adamw import AdamWConfig, init_adam
+from repro.utils.compile_cache import use_compile_cache
 from repro.utils.logging import get_logger
 
 log = get_logger("repro.train")
@@ -116,6 +117,7 @@ def main() -> None:
     ap.add_argument("--watchdog-s", type=float, default=None)
     ap.add_argument("--lr", type=float, default=3e-3)
     args = ap.parse_args()
+    use_compile_cache()
     train(args.arch, args.smoke, args.steps, args.batch, args.seq,
           args.ckpt_dir, args.resume, args.ckpt_every,
           args.qat_weight_bits, args.qat_act_bits, args.watchdog_s, args.lr)

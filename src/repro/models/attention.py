@@ -268,8 +268,8 @@ def attention_decode_paged(x: jnp.ndarray, p: Dict, cfg: ModelConfig, ctx,
         # read per query position with its own length mask — positions
         # past a query's own offset are masked by the read, so each read
         # is bitwise identical to the sequential one-token decode
-        kc = lp.k.at[pid, off].set(kq, mode="drop")
-        vc = lp.v.at[pid, off].set(vq, mode="drop")
+        kc = lp.k.at[pid, :, off].set(kq, mode="drop")
+        vc = lp.v.at[pid, :, off].set(vq, mode="drop")
         outs = [kops.paged_attention(q[:, j:j + 1], kc, vc, table,
                                      posb[:, j], lp.k_scale, lp.v_scale,
                                      lp.bits)
@@ -295,7 +295,7 @@ def _paged_update_attend_sharded(ctx, lp, q, knew, vnew, table, pos, pid,
     path is BIT-IDENTICAL to the replicated ``kops.paged_attention`` —
     the tp-vs-tp=1 engine parity contract.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.kvcache.paged import quantize_kv
 
@@ -306,16 +306,16 @@ def _paged_update_attend_sharded(ctx, lp, q, knew, vnew, table, pos, pid,
     bits = lp.bits
 
     def body(k_pool, v_pool, ks, vs, qg, kn, vn, tbl, ps, pidb, offb, spb):
-        # local kv-head block: (P, page, KV/tp, Dh'), scales (P, KV/tp)
+        # local kv-head block: (P, KV/tp, page, Dh'), scales (P, KV/tp)
         if bits < 16:
             kq = quantize_kv(kn[:, 0], ks[spb], bits)
             vq = quantize_kv(vn[:, 0], vs[spb], bits)
         else:
             kq = kn[:, 0].astype(k_pool.dtype)
             vq = vn[:, 0].astype(v_pool.dtype)
-        kc = k_pool.at[pidb, offb].set(kq, mode="drop")
-        vc = v_pool.at[pidb, offb].set(vq, mode="drop")
-        kvl = kc.shape[2]
+        kc = k_pool.at[pidb, :, offb].set(kq, mode="drop")
+        vc = v_pool.at[pidb, :, offb].set(vq, mode="drop")
+        kvl = kc.shape[1]
         ql = qg.reshape(b, 1, kvl * g, hd)         # local grouped heads
         ol = kops.paged_attention(ql, kc, vc, tbl, ps, ks, vs, bits)
         o = jax.lax.all_gather(ol, ax, axis=1, tiled=True)   # (B,KV,G,Dh)
@@ -328,14 +328,14 @@ def _paged_update_attend_sharded(ctx, lp, q, knew, vnew, table, pos, pid,
     rep1 = P(None)
     fn = shard_map(
         body, mesh=ctx.mesh,
-        in_specs=(P(None, None, ax, None), P(None, None, ax, None),
+        in_specs=(P(None, ax, None, None), P(None, ax, None, None),
                   P(None, ax), P(None, ax),
                   P(None, None, ax, None), P(None, None, ax, None),
                   P(None, None, ax, None),
                   rep2, rep1, rep1, rep1, rep1),
-        out_specs=(P(None, None, ax, None), P(None, None, ax, None),
+        out_specs=(P(None, ax, None, None), P(None, ax, None, None),
                    P(None, None, None, None)),
-        check_rep=False)
+        check_vma=False)
     if obs_rt.emitting():
         # counted from the REPLICATED positions (tp-invariant); the
         # ops-level emit inside the shard_map body is suspended below

@@ -29,7 +29,7 @@ from typing import Any, Dict, List, Mapping
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.qtensor import QTensor
@@ -481,7 +481,7 @@ class ShardedDequantContext(DequantContext):
                     mesh=mesh,
                     in_specs=(P(None, None), P(None, ax), P(None, ax),
                               P(None, None)),
-                    out_specs=P(None, None), check_rep=False)
+                    out_specs=P(None, None), check_vma=False)
             else:
                 fn = shard_map(
                     lambda a, d, sc, axs: self._qmm_row(
@@ -490,7 +490,7 @@ class ShardedDequantContext(DequantContext):
                     mesh=mesh,
                     in_specs=(P(None, None), P(ax, None), P(ax, None),
                               P(None, None)),
-                    out_specs=P(None, None), check_rep=False)
+                    out_specs=P(None, None), check_vma=False)
             with obs_rt.suspended():
                 y = fn(xq, w.data, ws2, xs)
             return y.astype(self.dtype).reshape(lead + (n,))
@@ -503,7 +503,7 @@ class ShardedDequantContext(DequantContext):
                 mesh=mesh,
                 in_specs=(P(None, None), P(None, ax), P(None, ax),
                           P(None, None)),
-                out_specs=P(None, None), check_rep=False)
+                out_specs=P(None, None), check_vma=False)
             with obs_rt.suspended():
                 y = fn(xq, w, s.reshape(1, -1), xs)
         else:
@@ -513,7 +513,7 @@ class ShardedDequantContext(DequantContext):
                 mesh=mesh,
                 in_specs=(P(None, None), P(ax, None), P(None, None),
                           P(None, None)),
-                out_specs=P(None, None), check_rep=False)
+                out_specs=P(None, None), check_vma=False)
             with obs_rt.suspended():
                 y = fn(xq, w, s.reshape(1, -1), xs)
         return y.astype(self.dtype).reshape(lead + (n,))
@@ -552,7 +552,7 @@ class ShardedDequantContext(DequantContext):
             mesh=self.mesh,
             in_specs=(P(None, None, None), P(None, None, None), P(None),
                       P(ax, None, None), P(ax, None, None)),
-            out_specs=P(None, None, None), check_rep=False)
+            out_specs=P(None, None, None), check_vma=False)
         with obs_rt.suspended():
             y = fn(xq, xs, cnt, w.data, w.scale)
         return y.astype(self.dtype)

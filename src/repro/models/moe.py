@@ -207,7 +207,7 @@ def moe_apply_ep(x: jnp.ndarray, p: Dict, cfg: ModelConfig, ctx, rules
     ride the same reduction as column-parallel FFNs over x_full.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh = rules.mesh
     mp = mesh.shape["model"]
@@ -269,7 +269,7 @@ def moe_apply_ep(x: jnp.ndarray, p: Dict, cfg: ModelConfig, ctx, rules
         body, mesh=mesh,
         in_specs=(x_spec, p_specs),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return mapped(x, p)
 

@@ -185,7 +185,7 @@ def kv_pool_bytes(num_pages: int, page_size: int, kv_heads: int,
     """Resident bytes of one layer's (k, v) page pools.  For bits < 16
     this equals ``storage_summary([lp.k_qt, lp.v_qt])["packed_bytes"]``
     of a live ``LayerPages`` exactly: payload at ``packed_size`` along
-    the head dim, plus the (P, 1, KV, 1) fp32 scale grids."""
+    the head dim, plus the (P, KV, 1, 1) fp32 scale grids."""
     if bits >= 16:
         return 2.0 * num_pages * page_size * kv_heads * head_dim * fp_bytes
     payload = num_pages * page_size * kv_heads * packed_size(head_dim, bits)

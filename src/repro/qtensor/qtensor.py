@@ -36,7 +36,7 @@ Scale semantics: ``scale.ndim == len(shape)``; every dim is either 1
 (g contiguous groups along that dim). ``expand_scale`` materializes the
 broadcastable view. Weight blocks use per-output-channel-per-group
 scales ``(K/group, N)`` for a ``(K, N)`` matmul; KV pages use per-page
-per-kv-head scales ``(P, 1, KV, 1)``.
+per-kv-head scales ``(P, KV, 1, 1)``.
 """
 from __future__ import annotations
 

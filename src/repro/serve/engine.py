@@ -749,7 +749,7 @@ class Engine:
         return jax.device_put(tree, self._repl)
 
     def _place_state(self, state: DecodeState) -> DecodeState:
-        """Mesh mode: paged pools shard by kv-head (payload axis 2, scale
+        """Mesh mode: paged pools shard by kv-head (payload and scale
         axis 1), everything else replicates."""
         if self._mesh is None:
             return state
@@ -758,7 +758,7 @@ class Engine:
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.kvcache.paged import LayerPages
         ax = self._tp_axis
-        ns_pool = NamedSharding(self._mesh, P(None, None, ax, None))
+        ns_pool = NamedSharding(self._mesh, P(None, ax, None, None))
         ns_scale = NamedSharding(self._mesh, P(None, ax))
         layers = {
             k: LayerPages(jax.device_put(lp.k, ns_pool),
@@ -919,6 +919,20 @@ class Engine:
                 dstate = self._insert_draft(dstate, ps, jnp.int32(0))
         slots = self._deactivate(slots, jnp.int32(0))
         jax.block_until_ready(slots["active"])
+
+    def prefill_logits(self, prompt) -> jnp.ndarray:
+        """(V,) logits the engine samples a request's first token from:
+        its own compiled chunked prefill over ``prompt`` on a fresh
+        batch-1 scratch state (no prefix reuse) — what oracle checks
+        compare against an independent reference forward."""
+        ecfg = self.ecfg
+        ps = self._put_repl(init_decode_state(self.cfg, 1, ecfg.max_len))
+        toks = jnp.asarray(prompt)[None]
+        logits = None
+        for lo in range(0, toks.shape[1], ecfg.prefill_chunk):
+            logits, ps = self._prefill(self.params, self.scales, ps,
+                                       toks[:, lo:lo + ecfg.prefill_chunk])
+        return logits[0, -1, ..., :self.cfg.vocab_size]
 
     # ------------------------------------------------------------------
     # clock

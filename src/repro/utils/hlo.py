@@ -21,16 +21,6 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized across JAX versions: older
-    releases return a one-element list of dicts (one per computation),
-    newer ones a plain dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
-
-
 COLLECTIVE_OPS = (
     "all-gather",
     "all-reduce",

@@ -159,8 +159,7 @@ def test_jaxpr_exact_widenings_not_flagged():
 
 
 def test_jaxpr_rpr101_float64():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(lambda x: x * 2.0)(
             jnp.zeros((3,), jnp.float64))
     fs = check_closed_jaxpr(closed, "fixture")
@@ -184,7 +183,7 @@ def _tp1_mesh():
 
 
 def test_jaxpr_rpr104_unproven_fp_psum():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _tp1_mesh()
@@ -195,7 +194,7 @@ def test_jaxpr_rpr104_unproven_fp_psum():
 
 
 def test_jaxpr_rpr104_proves_safe_constructions():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _tp1_mesh()

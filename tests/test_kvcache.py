@@ -167,15 +167,15 @@ def test_allocator_invariant_check_catches_corruption():
 @pytest.mark.parametrize("bits", [16, 8, 6, 4, 3])
 def test_paged_attention_kernel_matches_ref(bits, rng):
     P, page, KV, Dh, B, NP, G = 10, 8, 2, 16, 3, 4, 2
-    kf = rng.normal(size=(P, page, KV, Dh)).astype(np.float32)
-    vf = rng.normal(size=(P, page, KV, Dh)).astype(np.float32)
+    kf = rng.normal(size=(P, KV, page, Dh)).astype(np.float32)
+    vf = rng.normal(size=(P, KV, page, Dh)).astype(np.float32)
     ks = (np.abs(rng.normal(size=(P, KV))) * 0.05 + 0.02).astype(np.float32)
     vs = (np.abs(rng.normal(size=(P, KV))) * 0.05 + 0.02).astype(np.float32)
     if bits >= 16:
         k, v, kss, vss = jnp.asarray(kf), jnp.asarray(vf), None, None
     else:
-        k = quantize_kv(jnp.asarray(kf), jnp.asarray(ks)[:, None, :], bits)
-        v = quantize_kv(jnp.asarray(vf), jnp.asarray(vs)[:, None, :], bits)
+        k = quantize_kv(jnp.asarray(kf), jnp.asarray(ks)[:, :, None], bits)
+        v = quantize_kv(jnp.asarray(vf), jnp.asarray(vs)[:, :, None], bits)
         kss, vss = jnp.asarray(ks), jnp.asarray(vs)
         from repro.qtensor import PACKED_BITS, packed_size
         assert k.dtype == (jnp.uint8 if bits in PACKED_BITS else jnp.int8)

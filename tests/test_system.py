@@ -5,11 +5,16 @@ compute FIT from it → allocate mixed-precision bits → QAT → verify the
 quantized accuracy holds. Plus checkpoint/restart and watchdog behaviour
 of the training driver.
 """
+import os
+import subprocess
+import sys
 import time
+from pathlib import Path
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.core import build_report, greedy_allocate
 from repro.data.synthetic import ClassifyConfig, batched, classify_dataset
@@ -140,3 +145,44 @@ def test_watchdog_fires_and_supervise_restarts():
 
     restarts = supervise(flaky, max_restarts=5, backoff_s=0.01)
     assert restarts == 2
+
+
+ROOT = Path(__file__).resolve().parents[1]
+
+
+@pytest.mark.parametrize("kernels", [None, "ref"])
+def test_chip_smoke_refuses_without_chip(tmp_path, kernels):
+    """No TPU (this CPU host), or the oracle kernel route: the chip smoke
+    exits non-zero, names the platform it found, and prints no result."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("REPRO_KERNELS", None)
+    if kernels:
+        env["REPRO_KERNELS"] = kernels
+    r = subprocess.run([sys.executable, str(ROOT / "chip_smoke.py")],
+                       capture_output=True, text=True, env=env,
+                       cwd=tmp_path, timeout=300)
+    assert r.returncode != 0
+    assert "platform 'cpu'" in r.stderr
+    assert '"ok"' not in r.stdout
+
+
+def test_compile_cache_placed_from_outside(tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and is left to JAX; without it
+    the cache goes to the one fixed path in the checkout."""
+    code = (
+        "import jax; from repro.utils.compile_cache import "
+        "REPO_CACHE_DIR, use_compile_cache; d = use_compile_cache(); "
+        "jax.jit(lambda x: x + 1)(1.0).block_until_ready(); "
+        "print(d, '|', REPO_CACHE_DIR, '|', "
+        "jax.config.jax_compilation_cache_dir)")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               PYTHONPATH=str(ROOT / "src"))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, timeout=300, check=True)
+    used, repo_dir, configured = (
+        s.strip() for s in r.stdout.strip().splitlines()[-1].split("|"))
+    assert used == configured == str(tmp_path / "cache")
+    assert any((tmp_path / "cache").iterdir())       # written there
+    assert Path(repo_dir) == ROOT / ".jax_cache"
