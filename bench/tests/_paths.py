@@ -1,0 +1,9 @@
+"""Puts ``bench/`` and the program's ``src/`` on the import path for the
+benchmark's own tests."""
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (str(BENCH), str(BENCH.parent / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
