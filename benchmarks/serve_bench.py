@@ -259,10 +259,10 @@ def weight_storage_bench(pcfg_model, pparams, requests) -> dict:
 
 def observability_bench(pcfg_model, pparams, attempts: int = 8) -> dict:
     """Full observability (span tracing + in-graph device counters +
-    cadenced drains + device-timed dispatch spans) vs obs off, SAME
-    packed-W4 paged engine and workload — the instrument-heavy path:
-    qmm clip/saturation emits in the scan body, paged-attention read
-    counters, per-burst spans, per-dispatch perf timing.
+    cadenced drains) vs obs off, SAME packed-W4 paged engine and
+    workload — the instrument-heavy path: qmm clip/saturation emits in
+    the scan body, paged-attention read counters, per-phase trace
+    events.
 
     Scored on PAIRED attempts — each attempt runs off then on
     back-to-back and the ratio is taken within the pair, so slow drift
@@ -271,7 +271,8 @@ def observability_bench(pcfg_model, pparams, attempts: int = 8) -> dict:
     steady-state median of the pair ratios. The zero-sync design
     target is <= 3%% overhead, asserted by run(). Also reports the
     serving wall breakdown (prefill / decode / drain shares) and the
-    per-kind dispatch timing summary from the instrumented run.
+    engine's per-phase table (spans, wall, compiles, tokens) from the
+    instrumented run.
     """
     from repro.obs import ObsConfig
     from repro.serve import quantize_params
@@ -281,8 +282,7 @@ def observability_bench(pcfg_model, pparams, attempts: int = 8) -> dict:
                 max_new_tokens=GEN_RANGE[1], prefill_chunk=16,
                 decode_burst=16, int8_compute=True, kv_cache="paged",
                 page_size=16)
-    obs = ObsConfig(trace=True, device_metrics=True, drain_every=8,
-                    perf=True, time_every=4)
+    obs = ObsConfig(trace=True, device_metrics=True, drain_every=8)
     eng_off = Engine(qp, pcfg_model, EngineConfig(**base), scales=scales)
     eng_on = Engine(qp, pcfg_model, EngineConfig(**base, obs=obs),
                     scales=scales)
@@ -310,7 +310,7 @@ def observability_bench(pcfg_model, pparams, attempts: int = 8) -> dict:
         "tokens_per_s_on": round(best_on, 2),
         "on_over_off": best_ratio,
         "on_over_off_steady": steady_median(ratios),
-        "dispatch_timing": eng_on.perf.summary(),
+        "phases": on_m.phase_table(),
         "trace_events": eng_on.tracer.n_events,
         "counter_drains": eng_on.counters.n_drains,
         "counter_drain_s": drain_s,

@@ -318,14 +318,14 @@ def _smoke_engine(variant: str, mesh=None):
     else:
         cfg = dc.replace(cfg, scan_layers=False)
         params = init_params(cfg, jax.random.key(0))
-        if variant in ("qtensor", "paged", "sharded", "obs", "perf") \
+        if variant in ("qtensor", "paged", "sharded", "obs", "trace") \
                 or moe or spec:
             params, scales = quantize_params(params, 4, group_size=8)
             ecfg["int8_compute"] = True
         elif variant == "int8":
             params, scales = quantize_params_int8(params, 8)
             ecfg["int8_compute"] = True
-        if variant in ("paged", "sharded", "obs", "perf", "spec-paged"):
+        if variant in ("paged", "sharded", "obs", "trace", "spec-paged"):
             ecfg.update(kv_cache="paged", page_size=8)
         if spec:
             # draft/verify loop: W4 serving tree narrowed to a W3 draft,
@@ -345,14 +345,13 @@ def _smoke_engine(variant: str, mesh=None):
             # callbacks / transfers (RPR103) — drains happen outside it
             from repro.obs import ObsConfig
             ecfg["obs"] = ObsConfig(device_metrics=True)
-        if variant == "perf":
-            # full profiling stack on: device-timed dispatch spans +
-            # tracing + counters.  All timing is host-side around the
-            # audited syncs — the traced decode/prefill graphs must stay
-            # identical to the obs variant (no host callbacks, RPR103)
+        if variant == "trace":
+            # tracing + counters on: the phase spans and their timing are
+            # host-side around the audited syncs — the traced decode /
+            # prefill graphs must stay identical to the obs variant (no
+            # host callbacks, RPR103)
             from repro.obs import ObsConfig
-            ecfg["obs"] = ObsConfig(trace=True, device_metrics=True,
-                                    perf=True, time_every=1)
+            ecfg["obs"] = ObsConfig(trace=True, device_metrics=True)
     return Engine(params, cfg, EngineConfig(**ecfg), scales=scales)
 
 
@@ -421,7 +420,7 @@ def collect_targets(sharded: Optional[bool] = None) -> Tuple[
     # exactness rules, since either can serve as the parity oracle)
     # spec/spec-paged: the speculative draft/verify dispatch — both KV
     # lane shapes (dense int8 draft cache, paged packed-int4 draft pools)
-    for variant in ("dense", "qtensor", "int8", "paged", "obs", "perf",
+    for variant in ("dense", "qtensor", "int8", "paged", "obs", "trace",
                     "moe-grouped", "moe-dense", "spec", "spec-paged"):
         targets.extend(_engine_target_pair(variant))
     want_sharded = (len(jax.devices()) >= 2) if sharded is None else sharded

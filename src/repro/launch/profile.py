@@ -1,18 +1,19 @@
 """Performance profiling CLI (README "Performance profiling").
 
 Runs one FIT-quantized serve on the packed QTensor + paged-KV stack
-with the full profiling ObsConfig on (trace + device counters +
-device-timed dispatch spans), then joins three views per kernel site:
+with tracing and device counters on, then joins three views per kernel
+site:
 
-  measured  — dispatch walls from the audited syncs, with the
-              jit-cache-aware compile-vs-execute split;
+  measured  — the engine's decode-burst seconds (its phase spans);
   predicted — the analytic QTensor cost model's bytes-moved / op
               counts from the realized packed layouts;
   quality   — per-site FIT scores from a calibrated SensitivityReport.
 
 and emits the site -> (FIT score, predicted bytes, measured ms share)
-table, a Chrome trace carrying the device-timing track (validated), and
-a schema-versioned JSON payload.
+table, the per-phase timing of the engine's host loop (spans, wall
+seconds, compiles and tokens per phase), a Chrome trace on the
+profiler's epoch clock (validated), and a schema-versioned JSON
+payload.
 
   PYTHONPATH=src python -m repro.launch.profile --arch internlm2_1_8b \\
       --smoke --weight-bits 4 --group-size 8 --kv-bits 8 --requests 6 \\
@@ -54,7 +55,7 @@ def profile(arch: str = "internlm2_1_8b", smoke: bool = True,
             n_requests: int = 6, rate: float = 0.05,
             weight_bits: int = 4, avg_bits: Optional[float] = None,
             group_size: Optional[int] = 8, kv_bits: int = 8,
-            page_size: int = 8, time_every: int = 1, top: int = 12,
+            page_size: int = 8, top: int = 12,
             seed: int = 0, trace_path: Optional[str] = None,
             json_path: Optional[str] = None) -> Dict[str, Any]:
     """One profiled serve; returns (and optionally writes) the joined
@@ -78,8 +79,7 @@ def profile(arch: str = "internlm2_1_8b", smoke: bool = True,
         qparams, _ = quantize_params(params, weight_bits,
                                      group_size=group_size)
 
-    obs = ObsConfig(trace=True, device_metrics=True, perf=True,
-                    time_every=time_every, drain_every=4)
+    obs = ObsConfig(trace=True, device_metrics=True, drain_every=4)
     max_len = prompt_len + gen_len
     max_len += (-max_len) % page_size
     ecfg = EngineConfig(max_slots=batch, max_len=max_len,
@@ -107,11 +107,10 @@ def profile(arch: str = "internlm2_1_8b", smoke: bool = True,
           f"{summ.get('decode_tokens', 0)} decode tokens, "
           f"{summ.get('decode_tokens_per_s', 0.0):.1f} tok/s")
     print(format_table(rows, top=top))
-    timing = engine.perf.summary()
-    for kind, st in sorted(timing.items()):
-        print(f"{kind:>14}: n={st['count']:<4} exec={st['exec_s']:.4f}s "
-              f"compile={st['compile_s']:.4f}s "
-              f"({st['compiled']} cache-miss) sampled={st['sampled']}")
+    phases = metrics.phase_table()
+    for name, st in phases.items():
+        print(f"{name:>22}: n={st['count']:<4} wall={st['wall_s']:.4f}s "
+              f"compiles={st['compiles']:<3} tokens={st['tokens']}")
 
     payload = {
         "schema": PROFILE_SCHEMA,
@@ -122,7 +121,7 @@ def profile(arch: str = "internlm2_1_8b", smoke: bool = True,
         "group_size": group_size,
         "n_requests": len(finished),
         "sites": [r.as_dict() for r in rows],
-        "timing": timing,
+        "phases": phases,
         "roofline_totals": rl["totals"],
         "metrics": summ,
     }
@@ -131,7 +130,7 @@ def profile(arch: str = "internlm2_1_8b", smoke: bool = True,
         problems = validate_chrome_trace(engine.tracer.chrome_trace())
         if problems:
             raise AssertionError(f"invalid chrome trace: {problems[:3]}")
-        log.info("chrome trace (device track included) -> %s", trace_path)
+        log.info("chrome trace -> %s", trace_path)
     if json_path:
         with open(json_path, "w") as f:
             json.dump(payload, f, indent=1)
@@ -154,13 +153,11 @@ def main() -> None:
     ap.add_argument("--group-size", type=int, default=8)
     ap.add_argument("--kv-bits", type=int, default=8)
     ap.add_argument("--page-size", type=int, default=8)
-    ap.add_argument("--time-every", type=int, default=1,
-                    help="device-track trace cadence (1 = every dispatch)")
     ap.add_argument("--top", type=int, default=12,
                     help="table rows before the tail is folded")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="Chrome trace JSON with the device-timing track")
+                    help="Chrome trace JSON (engine phases and requests)")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="schema-versioned profile payload")
     a = ap.parse_args()
@@ -169,7 +166,7 @@ def main() -> None:
             prompt_len=a.prompt_len, gen_len=a.gen_len,
             n_requests=a.requests, rate=a.rate, weight_bits=a.weight_bits,
             avg_bits=a.avg_bits, group_size=a.group_size, kv_bits=a.kv_bits,
-            page_size=a.page_size, time_every=a.time_every, top=a.top,
+            page_size=a.page_size, top=a.top,
             seed=a.seed, trace_path=a.trace, json_path=a.json)
 
 

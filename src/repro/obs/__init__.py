@@ -1,9 +1,11 @@
 """repro.obs — serving observability (see README "Observability").
 
-Four layers over the continuous-batching engine:
+Five layers over the continuous-batching engine:
 
-  1. span tracing (``trace``)        — per-request lifecycle + per-
-     dispatch spans, Chrome trace-event JSON (Perfetto) + jsonl log;
+  1. span tracing (``trace``)        — the engine's host phases (always
+     timed and annotated for the JAX profiler, compiles counted per
+     phase) and per-request lifecycles, Chrome trace-event JSON on the
+     profiler's epoch clock (Perfetto) + jsonl log;
   2. zero-sync device metrics (``runtime``/``counters``) — counters
      accumulated INSIDE the jit'd decode burst, drained in bulk on a
      cadence (the only audited host transfer);
@@ -12,8 +14,7 @@ Four layers over the continuous-batching engine:
   4. FIT drift monitoring (``drift``) — online logit KL + activation-
      range drift vs the calibrated SensitivityReport, closing the loop
      between FIT's offline prediction and the live system;
-  5. performance profiling (``perf``) — device-timed dispatch spans
-     (host-side, around the audited syncs), the analytic QTensor cost
+  5. performance profiling (``perf``) — the analytic QTensor cost
      model, per-site FIT/bytes/ms attribution, and bench-history
      regression gating. See README "Performance profiling".
 

@@ -14,16 +14,13 @@ from typing import Optional
 class ObsConfig:
     """What to record and where to put it."""
 
-    trace: bool = False           # span tracing + jsonl event log
+    trace: bool = False           # Chrome trace JSON + jsonl event log
     device_metrics: bool = False  # in-jit counter accumulation + drains
     drain_every: int = 8          # bursts between counter drains (0: end only)
     stats_every: int = 4          # bursts between element-wise clip-stat
     #                               samples (act_sat / fq_clip reductions);
     #                               1 = every burst. Exact i32 counters
     #                               (tokens/steps/bursts) are never sampled.
-    perf: bool = False            # device-timed dispatch spans (obs.perf)
-    time_every: int = 1           # per-kind cadence of device-track trace
-    #                               mirroring; aggregation sees every sample
     trace_path: Optional[str] = None    # Chrome trace JSON output
     events_path: Optional[str] = None   # structured jsonl log output
     metrics_file: Optional[str] = None  # Prometheus text snapshot output
@@ -31,12 +28,10 @@ class ObsConfig:
 
     @property
     def enabled(self) -> bool:
-        return self.trace or self.device_metrics or self.perf
+        return self.trace or self.device_metrics
 
     def __post_init__(self):
         if self.drain_every < 0:
             raise ValueError("drain_every must be >= 0")
         if self.stats_every < 1:
             raise ValueError("stats_every must be >= 1")
-        if self.time_every < 1:
-            raise ValueError("time_every must be >= 1")

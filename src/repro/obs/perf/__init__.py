@@ -1,16 +1,14 @@
 """repro.obs.perf — performance observability (README "Performance
 profiling").
 
-Three views of the serving hot path, joined per site:
+Two views of the serving hot path, joined per site with what the
+engine measured itself (its phase spans, ``repro.obs.trace``):
 
-  measured  (``timing``)  — device-timed dispatch spans: the engine's
-      audited ``block_until_ready`` syncs feed a host-side aggregator
-      with a jit-cache-aware compile-vs-execute split, mirrored onto a
-      "device" track of the Chrome trace;
   predicted (``cost``)    — closed-form bytes-moved / op counts per
       kernel from the real packed layouts (qmm, paged_attention,
       int8_matmul), composed into a per-site roofline;
-  attributed (``attrib``) — the join of both with the calibrated
+  attributed (``attrib``) — the join of the predicted costs and the
+      engine's decode-burst seconds with the calibrated
       SensitivityReport: site -> (FIT score, predicted bytes,
       measured ms share) — the measured quality-vs-cost Pareto.
 
@@ -30,10 +28,9 @@ from repro.obs.perf.cost import (
 from repro.obs.perf.history import (
     HISTORY_SCHEMA, append_run, check_regression, load_history,
     metric_direction)
-from repro.obs.perf.timing import DispatchTimer
 
 __all__ = [
-    "HBM_BW", "HISTORY_SCHEMA", "INT8_OPS", "PEAK_FLOPS", "DispatchTimer",
+    "HBM_BW", "HISTORY_SCHEMA", "INT8_OPS", "PEAK_FLOPS",
     "KernelCost", "SiteRow", "append_run", "attribute", "check_regression",
     "format_table", "fp_matmul_cost", "grouped_qmm_cost",
     "grouped_qmm_weight_bytes", "int8_matmul_cost", "kv_pool_bytes",

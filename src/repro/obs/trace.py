@@ -1,16 +1,30 @@
-"""Span tracing: per-request lifecycle + per-dispatch spans.
+"""Span tracing: the engine's host phases, request lifecycles, and the
+compiles each phase caused.
 
-Host-side, append-only, and cheap (one ``perf_counter`` + dict append
-per span edge): the engine opens a span per request at admission and
-closes it at eviction (each request gets its own trace thread, so its
-admit / prefill-chunk / gather / evict children nest inside it), and
-puts batch-wide work — decode bursts, counter drains — on the engine
-thread.  Export is Chrome trace-event JSON (open in Perfetto:
-https://ui.perfetto.dev, "Open trace file") plus a structured jsonl
-event log for grepping.
+Phases (``Tracer.phase``) are the flat, disjoint spans of the engine's
+host loop — ``engine.admit``, ``engine.prefill_chunk``,
+``engine.insert``, ``engine.grow_tables``, ``engine.decode_burst`` /
+``engine.spec_burst``, ``engine.harvest``, ``engine.drain``,
+``engine.drift``, ``engine.wait_arrival``. Whether tracing is on or off,
+each one adds its wall time and count to the metrics object it is given
+(``phase_s`` / ``phase_n``), and enters a ``jax.profiler.TraceAnnotation``
+of its name, so any profile of the process holds it on the host plane,
+on the device trace's clock. With tracing on it also writes a complete
+event on the engine track. A listener on JAX's backend-compile event
+books each compile made inside ``counting`` to the phase open at that
+moment, or to ``(none)``.
 
-Disabled tracers swallow every call through a shared null context so an
-un-traced serve pays two attribute loads per site.
+Request lifecycles (one track per request: admit / prefill-chunk /
+evict children inside the request span) and the structured jsonl event
+log are recorded only with tracing on; they are not annotations.
+
+Timestamps are microseconds since ``origin_ns`` on the epoch clock
+(``time.time_ns``), the clock the profiler places its planes on through
+``profile_start_time``. The Chrome JSON carries the origin under
+``otherData``: a span starts at ``origin_ns + 1e3 * ts`` nanoseconds,
+which is ``profile_start_time + start_ns`` for the same span in an
+xplane of the run. Export is Chrome trace-event JSON (open in Perfetto:
+https://ui.perfetto.dev, "Open trace file") plus the jsonl log.
 """
 from __future__ import annotations
 
@@ -19,9 +33,92 @@ import json
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
-ENGINE_TID = 0          # batch-wide spans (bursts, drains, warmup)
+import jax
+
+ENGINE_TID = 0          # the engine's host loop: phases and the run span
 _REQ_TID_BASE = 1       # request r -> tid r + 1
-DEVICE_TID = -1         # device-timing track (sampled dispatch spans)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+NO_PHASE = "(none)"     # compiles of a counted run made outside any phase
+
+
+class _Books:
+    """Where the compile listener books: the metrics of the run being
+    counted, the phase open now, and the last run counted."""
+    run: Any = None
+    phase: Optional[str] = None
+    last: Any = None
+
+
+_BOOKS = _Books()
+
+
+def _on_duration(event: str, duration_secs: float, **_) -> None:
+    book = _BOOKS.run
+    if event == COMPILE_EVENT and book is not None:
+        key = _BOOKS.phase or NO_PHASE
+        book.compiles[key] = book.compiles.get(key, 0) + 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+@contextlib.contextmanager
+def counting(book):
+    """Book every XLA backend compile made inside the block to
+    ``book.compiles`` (a dict: phase name -> count)."""
+    _BOOKS.run = book
+    try:
+        yield book
+    finally:
+        _BOOKS.run = _BOOKS.phase = None
+        _BOOKS.last = book
+
+
+def last_counted():
+    """The book of the last run that ``counting`` finished in this
+    process (None before the first)."""
+    return _BOOKS.last
+
+
+class Phase:
+    """One phase span (``Tracer.phase``). ``s`` is its wall time in
+    seconds once it has ended; ``note`` adds arguments to its trace
+    event."""
+
+    __slots__ = ("_tracer", "name", "_book", "s", "event", "_ann", "_ts",
+                 "_t0")
+
+    def __init__(self, tracer: "Tracer", name: str, book):
+        self._tracer, self.name, self._book = tracer, name, book
+        self.s = 0.0
+        self.event: Optional[Dict[str, Any]] = None
+
+    def __enter__(self) -> "Phase":
+        _BOOKS.phase = self.name
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
+        if self._tracer.enabled:
+            self._ts = self._tracer._us()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.s = s = time.perf_counter() - self._t0
+        name, tr = self.name, self._tracer
+        if tr.enabled:
+            self.event = {"ph": "X", "name": name, "cat": "engine",
+                          "pid": tr.pid, "tid": ENGINE_TID, "ts": self._ts,
+                          "dur": tr._us() - self._ts, "args": {}}
+            tr._events.append(self.event)
+        self._ann.__exit__(*exc)
+        _BOOKS.phase = None
+        book = self._book
+        book.phase_s[name] = book.phase_s.get(name, 0.0) + s
+        book.phase_n[name] = book.phase_n.get(name, 0) + 1
+
+    def note(self, **args) -> None:
+        if self.event is not None:
+            self.event["args"].update(args)
 
 
 class Tracer:
@@ -30,7 +127,7 @@ class Tracer:
     def __init__(self, enabled: bool = True, pid: int = 1):
         self.enabled = enabled
         self.pid = pid
-        self._t0 = time.perf_counter()
+        self.origin_ns = time.time_ns()
         self._events: List[Dict[str, Any]] = []      # trace events
         self._log: List[Dict[str, Any]] = []         # jsonl records
         self._open: Dict[int, Tuple[str, str, int, float, Dict]] = {}
@@ -42,7 +139,7 @@ class Tracer:
 
     # -- clock ----------------------------------------------------------
     def _us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+        return (time.time_ns() - self.origin_ns) / 1e3
 
     # -- chrome metadata ------------------------------------------------
     def _meta(self, name: str, args: Dict, tid: int = 0) -> None:
@@ -60,19 +157,13 @@ class Tracer:
             self._name_tid(tid, f"req {int(req_id)}")
         return tid
 
-    def device_tid(self) -> int:
-        """The device-timing track (``repro.obs.perf.timing`` mirrors
-        sampled dispatch spans here, sibling to the engine thread)."""
-        if self.enabled:
-            self._name_tid(DEVICE_TID, "device")
-        return DEVICE_TID
-
-    def now_us(self) -> float:
-        """Trace-clock timestamp (µs since tracer start) — lets callers
-        that measured a duration themselves place a complete span."""
-        return self._us()
-
     # -- spans ----------------------------------------------------------
+    def phase(self, name: str, book) -> Phase:
+        """A phase span of the engine's host loop, booked to ``book``
+        (``phase_s`` / ``phase_n`` dicts) whether tracing is on or off;
+        see the module docstring."""
+        return Phase(self, name, book)
+
     def begin(self, name: str, cat: str = "serve", tid: int = ENGINE_TID,
               args: Optional[Dict] = None) -> Optional[int]:
         """Open a span; returns a handle for :meth:`end` (None if off)."""
@@ -107,19 +198,6 @@ class Tracer:
         finally:
             self.end(sid)
 
-    def complete(self, name: str, ts_us: float, dur_us: float,
-                 cat: str = "device", tid: int = DEVICE_TID,
-                 args: Optional[Dict] = None) -> None:
-        """Append an already-measured complete ("X") span at an explicit
-        [ts, ts+dur] on the trace clock — used for the device-timing
-        track, where the duration is known only after the sync."""
-        if not self.enabled:
-            return
-        self._events.append({
-            "ph": "X", "name": name, "cat": cat, "pid": self.pid,
-            "tid": tid, "ts": float(ts_us), "dur": max(float(dur_us), 0.0),
-            "args": dict(args or {})})
-
     def instant(self, name: str, tid: int = ENGINE_TID,
                 args: Optional[Dict] = None) -> None:
         if not self.enabled:
@@ -138,8 +216,10 @@ class Tracer:
 
     # -- export ---------------------------------------------------------
     def chrome_trace(self) -> Dict[str, Any]:
-        """The Perfetto-loadable trace object (open spans are dropped)."""
-        return {"traceEvents": list(self._events), "displayTimeUnit": "ms"}
+        """The Perfetto-loadable trace object (open spans are dropped);
+        ``otherData.origin_ns`` is the epoch time of ``ts`` 0."""
+        return {"traceEvents": list(self._events), "displayTimeUnit": "ms",
+                "otherData": {"origin_ns": self.origin_ns}}
 
     def write(self, path: str) -> None:
         with open(path, "w") as f:

@@ -83,8 +83,7 @@ from repro.kvcache.paged import (
     page_bytes_all_layers, scatter_span)
 from repro.obs import DeviceCounters, ObsConfig, Tracer, init_counters
 from repro.obs import runtime as obs_rt
-from repro.obs.perf.timing import DispatchTimer
-from repro.obs.trace import ENGINE_TID
+from repro.obs.trace import ENGINE_TID, counting
 from repro.serve.metrics import EngineMetrics
 from repro.serve.request import Request, RequestStatus
 from repro.serve.sampling import greedy_tokens, request_keys, sample_tokens
@@ -165,9 +164,6 @@ class Engine:
         self._obs: Optional[ObsConfig] = ecfg.obs
         self._obs_counters = bool(ecfg.obs and ecfg.obs.device_metrics)
         self.tracer = Tracer(enabled=bool(ecfg.obs and ecfg.obs.trace))
-        self.perf: Optional[DispatchTimer] = \
-            DispatchTimer(ecfg.obs.time_every) \
-            if ecfg.obs and ecfg.obs.perf else None
         self.counters = DeviceCounters()
         self._drift = None              # optional obs.drift.DriftMonitor
         self._runnable = 0              # slots with work available (obs)
@@ -800,10 +796,6 @@ class Engine:
         runs after decode bursts (never inside the dispatch)."""
         self._drift = monitor
 
-    def _jit_cache(self, name: str) -> Optional[int]:
-        from repro.obs.gauges import _jit_cache_size
-        return _jit_cache_size(getattr(self, name))
-
     @staticmethod
     def _mode_for(sampling_params) -> str:
         """The cheapest sampler specialization that serves these requests
@@ -955,7 +947,15 @@ class Engine:
     # ------------------------------------------------------------------
     def run(self, requests: Sequence[Request]
             ) -> Tuple[List[Request], EngineMetrics]:
-        """Serve ``requests`` to completion; returns (finished, metrics)."""
+        """Serve ``requests`` to completion; returns (finished, metrics).
+        The metrics are fresh per run, and count every compile the run
+        makes, per host phase (``repro.obs.trace``)."""
+        self.metrics = EngineMetrics(max_slots=self.ecfg.max_slots)
+        with counting(self.metrics):
+            finished = self._serve(requests)
+        return finished, self.metrics
+
+    def _serve(self, requests: Sequence[Request]) -> List[Request]:
         # the aggregate mode is correct for any subset of the requests; a
         # burst uses the cheapest warmed mode its active slots allow
         self._run_mode = (self._mode_for([r.sampling for r in requests])
@@ -990,7 +990,6 @@ class Engine:
             self._page_bytes = page_bytes_all_layers(cfg, self._pcfg)
         self._ticks = 0
         self._t0 = time.perf_counter()
-        self.metrics = EngineMetrics(max_slots=S)
         if self._paged:
             self.metrics.kv_total_pages = self._pcfg.num_pages
             self.metrics.kv_page_bytes = self._page_bytes
@@ -1035,7 +1034,9 @@ class Engine:
                             f"cannot hold request {pending[0].id} even "
                             "with every slot idle — raise kv_pages or "
                             "lower max_new_tokens")
-                    self._advance_to(pending[0].arrival_time)
+                    with self.tracer.phase("engine.wait_arrival",
+                                           self.metrics):
+                        self._advance_to(pending[0].arrival_time)
                 continue
 
             # ---- decode burst ----
@@ -1060,18 +1061,15 @@ class Engine:
             self._harvest(finished)
 
         if self._obs_counters:
-            d0 = self.counters.drain_s
-            self.counters.drain(self._ctr)       # final end-of-run drain
-            if self.perf is not None:
-                self.perf.record("drain", self.counters.drain_s - d0,
-                                 tracer=self.tracer)
+            with self.tracer.phase("engine.drain", self.metrics):
+                self.counters.drain(self._ctr)   # final end-of-run drain
             self.tracer.event("drain", n=self.counters.n_drains)
         if run_sid is not None:
             self.tracer.end(run_sid, {"requests": len(finished),
                                       "deferrals":
                                       self.metrics.admission_deferrals})
         finished.sort(key=lambda r: r.id)
-        return finished, self.metrics
+        return finished
 
     # ------------------------------------------------------------------
     def _pad_row(self, ids: List[int]) -> jnp.ndarray:
@@ -1112,73 +1110,75 @@ class Engine:
         return shared_len, partial_src, row, gather_ids
 
     def _admit(self, req: Request) -> bool:
-        ecfg = self.ecfg
-        slot = int(np.flatnonzero(~self._active)[0])
-        if req.prompt_len >= ecfg.max_len:
-            raise ValueError(
-                f"request {req.id}: prompt ({req.prompt_len}) does not fit "
-                f"the engine's max_len ({ecfg.max_len})")
-        # token budget is bounded by BOTH the KV capacity and the output
-        # buffer width — without the latter, tokens past the buffer would
-        # be computed and then scatter-dropped silently
-        budget = min(ecfg.max_len - req.prompt_len, ecfg.max_new_tokens)
-        if req.max_new_tokens > budget:
-            log.warning("request %d: max_new_tokens %d clipped to %d "
-                        "(max_len %d, max_new_tokens %d)", req.id,
-                        req.max_new_tokens, budget, ecfg.max_len,
-                        ecfg.max_new_tokens)
-            req.max_new_tokens = budget
+        """Prefill ``req`` into a free slot; False (nothing changed) when
+        the KV pool cannot hold it yet. Three kinds of phase span: the
+        admission itself, one per prefill chunk (decode bursts may run
+        between them), and the insert into the slot."""
+        ecfg, tr, m = self.ecfg, self.tracer, self.metrics
+        with tr.phase("engine.admit", m):
+            slot = int(np.flatnonzero(~self._active)[0])
+            if req.prompt_len >= ecfg.max_len:
+                raise ValueError(
+                    f"request {req.id}: prompt ({req.prompt_len}) does not "
+                    f"fit the engine's max_len ({ecfg.max_len})")
+            # token budget is bounded by BOTH the KV capacity and the
+            # output buffer width — without the latter, tokens past the
+            # buffer would be computed and then scatter-dropped silently
+            budget = min(ecfg.max_len - req.prompt_len, ecfg.max_new_tokens)
+            if req.max_new_tokens > budget:
+                log.warning("request %d: max_new_tokens %d clipped to %d "
+                            "(max_len %d, max_new_tokens %d)", req.id,
+                            req.max_new_tokens, budget, ecfg.max_len,
+                            ecfg.max_new_tokens)
+                req.max_new_tokens = budget
 
-        shared_len, partial_src, row, gather_ids = 0, None, None, None
-        if self._paged:
-            plan = self._plan_pages(slot, req)
-            if plan is None:
-                return False                   # pool full — try later
-            shared_len, partial_src, row, gather_ids = plan
-        req.slot, req.status = slot, RequestStatus.PREFILLING
-        req.t_admitted = self._now()
-        tr = self.tracer
-        rtid = tr.request_tid(req.id) if tr.enabled else ENGINE_TID
-        if tr.enabled:
-            # the request's lifecycle span (one per tid row in Perfetto);
-            # closed at eviction in _harvest
-            req.obs_span = tr.begin(f"request {req.id}", cat="request",
-                                    tid=rtid,
-                                    args={"prompt_len": req.prompt_len})
-        admit_sid = tr.begin("admit", cat="admit", tid=rtid) \
-            if tr.enabled else None
+            shared_len, partial_src, row, gather_ids = 0, None, None, None
+            if self._paged:
+                plan = self._plan_pages(slot, req)
+                if plan is None:
+                    return False               # pool full — try later
+                shared_len, partial_src, row, gather_ids = plan
+            req.slot, req.status = slot, RequestStatus.PREFILLING
+            req.t_admitted = self._now()
+            rtid = tr.request_tid(req.id) if tr.enabled else ENGINE_TID
+            if tr.enabled:
+                # the request's lifecycle span (one per tid row in
+                # Perfetto); closed at eviction in _harvest
+                req.obs_span = tr.begin(f"request {req.id}", cat="request",
+                                        tid=rtid,
+                                        args={"prompt_len": req.prompt_len})
+            admit_sid = tr.begin("admit", cat="admit", tid=rtid) \
+                if tr.enabled else None
 
-        pstate = self._put_repl(init_decode_state(self.cfg, 1, ecfg.max_len))
-        if shared_len > 0:
-            # prefix reuse: seed the scratch cache from the shared pages
-            # and prefill only the suffix (the engine's prefill saving)
-            with tr.span("gather_prefix", cat="admit", tid=rtid,
-                         args={"shared_len": shared_len}):
-                kvd = self._gather(self._state, self._pad_row(gather_ids),
-                                   jnp.int32(shared_len))
-            pstate = pstate._replace(pos=jnp.int32(shared_len), kv=kvd)
-        prompt = jnp.asarray(req.prompt)[None]               # (1, P[, CB])
+            pstate = self._put_repl(init_decode_state(self.cfg, 1,
+                                                      ecfg.max_len))
+            if shared_len > 0:
+                # prefix reuse: seed the scratch cache from the shared
+                # pages and prefill only the suffix (the engine's
+                # prefill saving)
+                with tr.span("gather_prefix", cat="admit", tid=rtid,
+                             args={"shared_len": shared_len}):
+                    kvd = self._gather(self._state,
+                                       self._pad_row(gather_ids),
+                                       jnp.int32(shared_len))
+                pstate = pstate._replace(pos=jnp.int32(shared_len), kv=kvd)
+            prompt = jnp.asarray(req.prompt)[None]           # (1, P[, CB])
+
         logits = None
         for lo in range(shared_len, req.prompt_len, ecfg.prefill_chunk):
-            chunk = prompt[:, lo:lo + ecfg.prefill_chunk]
-            t0 = time.perf_counter()
-            p0 = self._jit_cache("_prefill") \
-                if self.perf is not None else None
+            n_decoding = int(self._active.sum())
             sid = tr.begin("prefill_chunk", cat="prefill", tid=rtid) \
                 if tr.enabled else None
-            logits, pstate = self._prefill(self.params, self.scales,
-                                           pstate, chunk)
-            jax.block_until_ready(logits)
-            dt = time.perf_counter() - t0
+            with tr.phase("engine.prefill_chunk", m) as ph:
+                chunk = prompt[:, lo:lo + ecfg.prefill_chunk]
+                logits, pstate = self._prefill(self.params, self.scales,
+                                               pstate, chunk)
+                jax.block_until_ready(logits)
+            ph.note(tokens=int(chunk.shape[1]), req=req.id,
+                    n_decoding=n_decoding)
             if sid is not None:
                 tr.end(sid, {"tokens": int(chunk.shape[1]), "lo": lo})
-            if self.perf is not None:
-                p1 = self._jit_cache("_prefill")
-                self.perf.record("prefill_chunk", dt,
-                                 tokens=int(chunk.shape[1]),
-                                 compiled=bool(p1 is not None and p1 != p0),
-                                 tracer=tr)
-            self.metrics.record_prefill(dt, chunk.shape[1])
+            m.record_prefill(ph.s, chunk.shape[1], n_decoding)
             if self.ecfg.clock == "steps":
                 self._ticks += chunk.shape[1]
             # chunked prefill: keep in-flight decodes moving between
@@ -1191,81 +1191,84 @@ class Engine:
                 rem = (self._budget - self._nwritten)[self._active]
                 self._burst(min(ecfg.interleave_steps, int(rem.min())))
 
-        s = req.sampling
-        tok0 = self._sample_first(
-            self.scales, logits[:, -1],
-            jnp.asarray([s.seed], jnp.int32),
-            jnp.asarray([s.temperature], jnp.float32),
-            jnp.asarray([s.top_k], jnp.int32),
-            jnp.asarray([s.top_p], jnp.float32))
-        if self._paged:
-            if partial_src is not None:
-                # copy-on-write: own the partially-filled boundary page
-                # before the suffix insert writes into it
-                dst = row[len(gather_ids) - 1]
-                self._state = self._copy_page(self._state,
-                                              jnp.int32(partial_src),
-                                              jnp.int32(dst))
-            plen = req.prompt_len
-            limit = min(plen + req.max_new_tokens, ecfg.max_len)
-            self._state, self._tok, self._out, self._dslots = \
-                self._insert_paged(
-                    self._state, pstate, jnp.int32(slot), self._pad_row(row),
-                    jnp.int32(shared_len), jnp.int32(plen), jnp.int32(limit),
-                    self._tok, tok0, self._out, self._dslots,
-                    jnp.int32(s.seed), jnp.float32(s.temperature),
-                    jnp.int32(s.top_k), jnp.float32(s.top_p),
-                    jnp.int32(req.max_new_tokens))
-            self._alloc.register_prompt(np.asarray(req.prompt), row, plen)
-            self._rows[slot] = row
-            self._pos_h[slot] = plen
-            self._limit_h[slot] = limit
-            self.metrics.record_kv_usage(self._alloc.pages_in_use)
-            self.metrics.kv_shared_tokens = self._alloc.shared_tokens
-            self.metrics.kv_cow_copies = self._alloc.cow_copies
-        else:
-            self._state, self._tok, self._out, self._dslots = self._insert(
-                self._state, pstate, jnp.int32(slot), self._tok, tok0,
-                self._out, self._dslots, jnp.int32(s.seed),
-                jnp.float32(s.temperature), jnp.int32(s.top_k),
-                jnp.float32(s.top_p), jnp.int32(req.max_new_tokens))
-
-        if self._spec is not None:
-            # seed the draft lane from the SAME prefilled scratch state:
-            # target-computed prompt KV quantized onto the draft grid
+        with tr.phase("engine.insert", m):
+            s = req.sampling
+            tok0 = self._sample_first(
+                self.scales, logits[:, -1],
+                jnp.asarray([s.seed], jnp.int32),
+                jnp.asarray([s.temperature], jnp.float32),
+                jnp.asarray([s.top_k], jnp.int32),
+                jnp.asarray([s.top_p], jnp.float32))
             if self._paged:
                 if partial_src is not None:
-                    # mirror the serving COW copy before the suffix
-                    # scatter writes into the owned boundary page
+                    # copy-on-write: own the partially-filled boundary
+                    # page before the suffix insert writes into it
                     dst = row[len(gather_ids) - 1]
-                    self._dstate = self._copy_page_draft(
-                        self._dstate, jnp.int32(partial_src),
-                        jnp.int32(dst))
-                self._dstate = self._insert_draft_paged(
-                    self._dstate, pstate, self._pad_row(row),
-                    jnp.int32(slot), jnp.int32(shared_len),
-                    jnp.int32(req.prompt_len))
+                    self._state = self._copy_page(self._state,
+                                                  jnp.int32(partial_src),
+                                                  jnp.int32(dst))
+                plen = req.prompt_len
+                limit = min(plen + req.max_new_tokens, ecfg.max_len)
+                self._state, self._tok, self._out, self._dslots = \
+                    self._insert_paged(
+                        self._state, pstate, jnp.int32(slot),
+                        self._pad_row(row), jnp.int32(shared_len),
+                        jnp.int32(plen), jnp.int32(limit), self._tok, tok0,
+                        self._out, self._dslots, jnp.int32(s.seed),
+                        jnp.float32(s.temperature), jnp.int32(s.top_k),
+                        jnp.float32(s.top_p), jnp.int32(req.max_new_tokens))
+                self._alloc.register_prompt(np.asarray(req.prompt), row, plen)
+                self._rows[slot] = row
+                self._pos_h[slot] = plen
+                self._limit_h[slot] = limit
+                m.record_kv_usage(self._alloc.pages_in_use)
+                m.kv_shared_tokens = self._alloc.shared_tokens
+                m.kv_cow_copies = self._alloc.cow_copies
             else:
-                self._dstate = self._insert_draft(self._dstate, pstate,
-                                                  jnp.int32(slot))
-            # the catch-up pair's first element for the first dispatch:
-            # the LAST PROMPT token (stream position prompt_len - 1,
-            # where the lagged draft lane starts)
-            cb = self._tok_shape[2:]
-            self._ptok = self._ptok.at[slot].set(
-                jnp.asarray(np.asarray(req.prompt)[-1],
-                            jnp.int32).reshape((1,) + cb))
+                self._state, self._tok, self._out, self._dslots = \
+                    self._insert(
+                        self._state, pstate, jnp.int32(slot), self._tok,
+                        tok0, self._out, self._dslots, jnp.int32(s.seed),
+                        jnp.float32(s.temperature), jnp.int32(s.top_k),
+                        jnp.float32(s.top_p), jnp.int32(req.max_new_tokens))
 
-        self._slots[slot] = req
-        self._active[slot] = True
-        self._nwritten[slot] = 1
-        self._budget[slot] = req.max_new_tokens
-        req.t_first_token = self._now()
-        req.status = RequestStatus.RUNNING
-        if admit_sid is not None:
-            tr.end(admit_sid, {"slot": slot, "shared_len": shared_len})
-        tr.event("admit", req=req.id, slot=slot, shared_len=shared_len,
-                 prompt_len=req.prompt_len)
+            if self._spec is not None:
+                # seed the draft lane from the SAME prefilled scratch
+                # state: target-computed prompt KV quantized onto the
+                # draft grid
+                if self._paged:
+                    if partial_src is not None:
+                        # mirror the serving COW copy before the suffix
+                        # scatter writes into the owned boundary page
+                        dst = row[len(gather_ids) - 1]
+                        self._dstate = self._copy_page_draft(
+                            self._dstate, jnp.int32(partial_src),
+                            jnp.int32(dst))
+                    self._dstate = self._insert_draft_paged(
+                        self._dstate, pstate, self._pad_row(row),
+                        jnp.int32(slot), jnp.int32(shared_len),
+                        jnp.int32(req.prompt_len))
+                else:
+                    self._dstate = self._insert_draft(self._dstate, pstate,
+                                                      jnp.int32(slot))
+                # the catch-up pair's first element for the first
+                # dispatch: the LAST PROMPT token (stream position
+                # prompt_len - 1, where the lagged draft lane starts)
+                cb = self._tok_shape[2:]
+                self._ptok = self._ptok.at[slot].set(
+                    jnp.asarray(np.asarray(req.prompt)[-1],
+                                jnp.int32).reshape((1,) + cb))
+
+            self._slots[slot] = req
+            self._active[slot] = True
+            self._nwritten[slot] = 1
+            self._budget[slot] = req.max_new_tokens
+            req.t_first_token = self._now()
+            req.status = RequestStatus.RUNNING
+            if admit_sid is not None:
+                tr.end(admit_sid, {"slot": slot, "shared_len": shared_len})
+            tr.event("admit", req=req.id, slot=slot, shared_len=shared_len,
+                     prompt_len=req.prompt_len)
         return True
 
     # ------------------------------------------------------------------
@@ -1276,24 +1279,27 @@ class Engine:
         ONE full-table upload — (S, NP) int32 is tiny, and one dispatch
         beats one per slot on the decode hot path. At most
         ceil(steps/page) new pages per slot per burst."""
-        page = self._pcfg.page_size
-        grew = False
-        for b in np.flatnonzero(self._active):
-            need = -(-min(self._pos_h[b] + steps, self._limit_h[b]) // page)
-            have = len(self._rows[b])
-            if need <= have:
-                continue
-            ids = self._alloc.allocate(need - have, owner=int(b))
-            assert ids is not None, "reservation accounting broken"
-            self._rows[b] += ids
-            grew = True
-        if grew:
-            table = np.full((self.ecfg.max_slots, self._pcfg.pages_per_slot),
-                            self._pcfg.num_pages, np.int32)
+        with self.tracer.phase("engine.grow_tables", self.metrics):
+            page = self._pcfg.page_size
+            grew = False
             for b in np.flatnonzero(self._active):
-                table[b, :len(self._rows[b])] = self._rows[b]
-            self._state = self._set_table(self._state, jnp.asarray(table))
-            self.metrics.record_kv_usage(self._alloc.pages_in_use)
+                need = -(-min(self._pos_h[b] + steps, self._limit_h[b])
+                         // page)
+                have = len(self._rows[b])
+                if need <= have:
+                    continue
+                ids = self._alloc.allocate(need - have, owner=int(b))
+                assert ids is not None, "reservation accounting broken"
+                self._rows[b] += ids
+                grew = True
+            if grew:
+                table = np.full((self.ecfg.max_slots,
+                                 self._pcfg.pages_per_slot),
+                                self._pcfg.num_pages, np.int32)
+                for b in np.flatnonzero(self._active):
+                    table[b, :len(self._rows[b])] = self._rows[b]
+                self._state = self._set_table(self._state, jnp.asarray(table))
+                self.metrics.record_kv_usage(self._alloc.pages_in_use)
 
     def _burst(self, steps: int) -> None:
         if steps <= 0:
@@ -1313,25 +1319,19 @@ class Engine:
         exact = self._mode_for([self._slots[b].sampling
                                 for b in np.flatnonzero(self._active)])
         mode = exact if exact in self._warmed_modes else self._run_mode
-        tr = self.tracer
         n_active = int(self._active.sum())
-        timed = tr.enabled or self.perf is not None
-        c0 = self._jit_cache("_engine_step") if timed else None
-        sid = tr.begin("decode_burst", cat="decode", tid=ENGINE_TID) \
-            if tr.enabled else None
         # sampled clip-stat cadence: every stats_every-th burst carries
         # the element-wise saturation reductions; the rest run the cheap
         # counter graph (scalar call/token adds only)
         stats = bool(self._ctr) and \
             self._burst_i % self._obs.stats_every == 0
-        t0 = time.perf_counter()
-        (self._state, self._tok, self._out, self._dslots,
-         self._ctr) = self._engine_step(
-            self.params, self.scales, self._state, self._tok, self._out,
-            self._dslots, self._ctr, steps=steps, mode=mode, stats=stats)
-        # the wall-timing sync IS the burst-latency measurement
-        jax.block_until_ready(self._tok)  # rpr-ok: RPR008 timed sync — the burst latency metric is this wait
-        wall = time.perf_counter() - t0
+        # the span's synced wall IS the burst-latency measurement
+        with self.tracer.phase("engine.decode_burst", self.metrics) as ph:
+            (self._state, self._tok, self._out, self._dslots,
+             self._ctr) = self._engine_step(
+                self.params, self.scales, self._state, self._tok, self._out,
+                self._dslots, self._ctr, steps=steps, mode=mode, stats=stats)
+            jax.block_until_ready(self._tok)  # rpr-ok: RPR008 timed sync — the burst latency metric is this wait
         # host mirror of the device-side clamp (tokens past a slot's
         # budget were dropped)
         before = self._nwritten[self._active]
@@ -1340,40 +1340,29 @@ class Engine:
         if self._paged:
             self._pos_h[self._active] += steps
         n_tokens = int((after - before).sum())
-        compiled = False
-        if timed:
-            c1 = self._jit_cache("_engine_step")
-            compiled = bool(c1 is not None and c1 != c0)
-        if sid is not None:
-            tr.end(sid, {"steps": steps, "mode": mode,
-                         "n_active": n_active, "tokens": n_tokens,
-                         "tp": self._tp, "compiled": compiled})
-        if self.perf is not None:
-            # the synced wall above is the device-timed dispatch sample;
-            # cache-miss dispatches are booked to the compile bucket
-            self.perf.record("decode_burst", wall, tokens=n_tokens,
-                             compiled=compiled, tracer=tr,
-                             args={"steps": steps, "n_active": n_active})
-        self.metrics.record_burst(wall, steps, n_active,
+        ph.note(steps=steps, mode=mode, n_active=n_active, tokens=n_tokens,
+                tp=self._tp)
+        self.metrics.record_burst(ph.s, steps, n_active,
                                   n_tokens=n_tokens,
                                   n_runnable=max(n_active, self._runnable),
                                   per_slot_tokens=[int(x)
                                                    for x in after - before])
+        self._after_burst(steps)
+
+    def _after_burst(self, steps: int) -> None:
+        """Per-burst bookkeeping: the step clock, the cadenced bulk
+        counter drain (the ONE audited host-transfer site on the serving
+        loop, see obs.counters) and the drift tap."""
         if self.ecfg.clock == "steps":
             self._ticks += steps
         self._burst_i += 1
         de = self._obs.drain_every if self._obs is not None else 0
         if self._obs_counters and de and self._burst_i % de == 0:
-            # cadenced bulk drain — the ONE audited host-transfer site on
-            # the serving loop (see obs.counters)
-            with tr.span("drain", cat="obs", tid=ENGINE_TID):
-                d0 = self.counters.drain_s
+            with self.tracer.phase("engine.drain", self.metrics):
                 self.counters.drain(self._ctr)
-                if self.perf is not None:
-                    self.perf.record("drain",
-                                     self.counters.drain_s - d0, tracer=tr)
         if self._drift is not None:
-            self._drift.observe(steps)
+            with self.tracer.phase("engine.drift", self.metrics):
+                self._drift.observe(steps)
 
     def _spec_burst(self) -> None:
         """One draft/verify dispatch (see ``spec_step_fn``). The only
@@ -1389,22 +1378,16 @@ class Engine:
         exact = self._mode_for([self._slots[b].sampling
                                 for b in np.flatnonzero(self._active)])
         mode = exact if exact in self._warmed_modes else self._run_mode
-        tr = self.tracer
         n_active = int(self._active.sum())
-        timed = tr.enabled or self.perf is not None
-        c0 = self._jit_cache("_spec_step") if timed else None
-        sid = tr.begin("spec_burst", cat="decode", tid=ENGINE_TID) \
-            if tr.enabled else None
         stats = bool(self._ctr) and \
             self._burst_i % self._obs.stats_every == 0
-        t0 = time.perf_counter()
-        (self._state, self._dstate, self._ptok, self._tok, self._out,
-         self._dslots, self._ctr, n_emit) = self._spec_step(
-            self.params, self.scales, self._draft_params, self._state,
-            self._dstate, self._ptok, self._tok, self._out, self._dslots,
-            self._ctr, k=k, mode=mode, stats=stats)
-        ne = np.asarray(jax.device_get(n_emit))  # rpr-ok: RPR008 timed sync — scheduler control dependency + the burst latency metric
-        wall = time.perf_counter() - t0
+        with self.tracer.phase("engine.spec_burst", self.metrics) as ph:
+            (self._state, self._dstate, self._ptok, self._tok, self._out,
+             self._dslots, self._ctr, n_emit) = self._spec_step(
+                self.params, self.scales, self._draft_params, self._state,
+                self._dstate, self._ptok, self._tok, self._out, self._dslots,
+                self._ctr, k=k, mode=mode, stats=stats)
+            ne = np.asarray(jax.device_get(n_emit))  # rpr-ok: RPR008 timed sync — scheduler control dependency + the burst latency metric
         # exact host mirror of the device update (n_emit is already
         # budget-clamped and zero for inactive slots)
         self._nwritten[self._active] += ne[self._active]
@@ -1418,34 +1401,12 @@ class Engine:
         # match run (the device spec_accepted counter is exact)
         self.spec_stats["accepted"] += int(
             np.maximum(ne[self._active] - 1, 0).sum())
-        compiled = False
-        if timed:
-            c1 = self._jit_cache("_spec_step")
-            compiled = bool(c1 is not None and c1 != c0)
-        if sid is not None:
-            tr.end(sid, {"k": k, "mode": mode, "n_active": n_active,
-                         "tokens": n_tokens, "compiled": compiled})
-        if self.perf is not None:
-            self.perf.record("spec_burst", wall, tokens=n_tokens,
-                             compiled=compiled, tracer=tr,
-                             args={"k": k, "n_active": n_active})
+        ph.note(k=k, mode=mode, n_active=n_active, tokens=n_tokens)
         self.metrics.record_burst(
-            wall, k + 1, n_active, n_tokens=n_tokens,
+            ph.s, k + 1, n_active, n_tokens=n_tokens,
             n_runnable=max(n_active, self._runnable),
             per_slot_tokens=[int(x) for x in ne[self._active]])
-        if self.ecfg.clock == "steps":
-            self._ticks += k + 1
-        self._burst_i += 1
-        de = self._obs.drain_every if self._obs is not None else 0
-        if self._obs_counters and de and self._burst_i % de == 0:
-            with tr.span("drain", cat="obs", tid=ENGINE_TID):
-                d0 = self.counters.drain_s
-                self.counters.drain(self._ctr)
-                if self.perf is not None:
-                    self.perf.record("drain",
-                                     self.counters.drain_s - d0, tracer=tr)
-        if self._drift is not None:
-            self._drift.observe(k + 1)
+        self._after_burst(k + 1)
 
     # ------------------------------------------------------------------
     def _harvest(self, finished: List[Request]) -> None:
@@ -1456,49 +1417,50 @@ class Engine:
                 and all(self._slots[b].eos_id is None
                         for b in np.flatnonzero(self._active))):
             return                      # nothing can have finished
-        for b in np.flatnonzero(self._active):
-            req = self._slots[b]
-            count = int(self._nwritten[b])
-            done = count >= self._budget[b]
-            toks = None
-            if done or req.eos_id is not None:
-                toks = np.asarray(self._out[b, :count])
-                if req.eos_id is not None:
-                    flat = toks if toks.ndim == 1 else toks[:, 0]
-                    hits = np.flatnonzero(flat == req.eos_id)
-                    if hits.size:
-                        toks = toks[:hits[0] + 1]
-                        done = True
-            if not done:
-                continue
-            req.output_tokens = toks
-            req.t_finished = self._now()
-            req.status = RequestStatus.FINISHED
-            self.metrics.record_request(req)
-            finished.append(req)
-            tr = self.tracer
-            evict_sid = tr.begin("evict", cat="evict",
-                                 tid=tr.request_tid(req.id),
-                                 args={"slot": int(b)}) \
-                if tr.enabled else None
-            self._slots[b] = None          # slot freed: backfilled by the
-            self._active[b] = False        # admission loop next iteration
-            self._dslots = self._deactivate(self._dslots, jnp.int32(b))
-            if self._paged:
-                # recycle the request's pages (shared pages survive via
-                # their refcount) and unmap the slot's device row so a
-                # stale slot can never touch a recycled page
-                self.metrics.record_kv_request(
-                    len(self._rows[b]) * self._page_bytes)
-                self._alloc.release(self._rows[b])
-                self._alloc.unreserve(int(b))
-                self._rows[b] = []
-                self._pos_h[b] = self._limit_h[b] = 0
-                self._state = self._clear_slot(self._state, jnp.int32(b))
-            if evict_sid is not None:
-                tr.end(evict_sid)
-                span = getattr(req, "obs_span", None)
-                if span is not None:
-                    tr.end(span, {"tokens": int(len(toks))})
-            tr.event("finish", req=req.id, slot=int(b),
-                     tokens=int(len(toks)))
+        with self.tracer.phase("engine.harvest", self.metrics):
+            for b in np.flatnonzero(self._active):
+                req = self._slots[b]
+                count = int(self._nwritten[b])
+                done = count >= self._budget[b]
+                toks = None
+                if done or req.eos_id is not None:
+                    toks = np.asarray(self._out[b, :count])
+                    if req.eos_id is not None:
+                        flat = toks if toks.ndim == 1 else toks[:, 0]
+                        hits = np.flatnonzero(flat == req.eos_id)
+                        if hits.size:
+                            toks = toks[:hits[0] + 1]
+                            done = True
+                if not done:
+                    continue
+                req.output_tokens = toks
+                req.t_finished = self._now()
+                req.status = RequestStatus.FINISHED
+                self.metrics.record_request(req)
+                finished.append(req)
+                tr = self.tracer
+                evict_sid = tr.begin("evict", cat="evict",
+                                     tid=tr.request_tid(req.id),
+                                     args={"slot": int(b)}) \
+                    if tr.enabled else None
+                self._slots[b] = None      # slot freed: backfilled by the
+                self._active[b] = False    # admission loop next iteration
+                self._dslots = self._deactivate(self._dslots, jnp.int32(b))
+                if self._paged:
+                    # recycle the request's pages (shared pages survive via
+                    # their refcount) and unmap the slot's device row so a
+                    # stale slot can never touch a recycled page
+                    self.metrics.record_kv_request(
+                        len(self._rows[b]) * self._page_bytes)
+                    self._alloc.release(self._rows[b])
+                    self._alloc.unreserve(int(b))
+                    self._rows[b] = []
+                    self._pos_h[b] = self._limit_h[b] = 0
+                    self._state = self._clear_slot(self._state, jnp.int32(b))
+                if evict_sid is not None:
+                    tr.end(evict_sid)
+                    span = getattr(req, "obs_span", None)
+                    if span is not None:
+                        tr.end(span, {"tokens": int(len(toks))})
+                tr.event("finish", req=req.id, slot=int(b),
+                         tokens=int(len(toks)))

@@ -45,11 +45,25 @@ class EngineMetrics:
     kv_req_bytes: List[float] = dataclasses.field(default_factory=list)
     kv_shared_tokens: int = 0         # prefill tokens skipped via sharing
     kv_cow_copies: int = 0
+    # host phases of the run loop (``repro.obs.trace.Tracer.phase``):
+    # wall seconds and count per phase, and XLA backend compiles per
+    # phase (``(none)``: made outside every phase)
+    phase_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    phase_n: Dict[str, int] = dataclasses.field(default_factory=dict)
+    compiles: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # slot-seconds decoding requests spent waiting behind another
+    # request's prefill chunk, and slot-seconds spent in decode bursts
+    stall_slot_s: float = 0.0
+    decode_slot_s: float = 0.0
 
-    def record_prefill(self, wall_dt: float, n_tokens: int) -> None:
+    def record_prefill(self, wall_dt: float, n_tokens: int,
+                       n_decoding: int = 0) -> None:
+        """One prefill chunk's synced wall; ``n_decoding`` slots were
+        mid-decode, held while it ran."""
         self.prefill_s += wall_dt
         self.prefill_tokens += n_tokens
         self.prefill_dispatches += 1
+        self.stall_slot_s += wall_dt * n_decoding
 
     def record_burst(self, wall_dt: float, steps: int, n_active: int,
                      n_tokens: Optional[int] = None,
@@ -80,6 +94,7 @@ class EngineMetrics:
         if n_runnable is None:
             n_runnable = self.max_slots
         self.decode_s += wall_dt
+        self.decode_slot_s += wall_dt * n_active
         self.decode_tokens += n_tokens
         self.decode_steps += steps
         self.occupied_slot_steps += n_tokens
@@ -110,6 +125,19 @@ class EngineMetrics:
         """Page footprint (bytes across all layer pools) of one finished
         request — shared pages count toward every sharer."""
         self.kv_req_bytes.append(float(hbm_bytes))
+
+    def phase_table(self) -> Dict[str, Dict[str, float]]:
+        """Per phase: spans, wall seconds, compiles booked to it, and the
+        tokens its dispatches ran (prefill chunks: prompt tokens; bursts:
+        decode tokens)."""
+        tokens = {"engine.prefill_chunk": self.prefill_tokens,
+                  "engine.decode_burst": self.decode_tokens,
+                  "engine.spec_burst": self.decode_tokens}
+        return {name: {"count": self.phase_n.get(name, 0),
+                       "wall_s": self.phase_s.get(name, 0.0),
+                       "compiles": self.compiles.get(name, 0),
+                       "tokens": tokens.get(name, 0)}
+                for name in sorted({*self.phase_n, *self.compiles})}
 
     def summary(self) -> Dict:
         slot_steps = self.decode_steps * self.max_slots

@@ -149,8 +149,8 @@ def test_trace_schema_and_request_nesting(tmp_path):
     obj = eng.tracer.chrome_trace()
     assert validate_chrome_trace(obj) == []
     names = {e["name"] for e in obj["traceEvents"] if e.get("ph") == "X"}
-    for want in ("run", "admit", "prefill_chunk", "decode_burst", "drain",
-                 "evict"):
+    for want in ("run", "admit", "prefill_chunk", "engine.decode_burst",
+                 "engine.drain", "evict"):
         assert want in names, (want, names)
     assert any(n.startswith("request") for n in names)
     # every request's children live inside its request span, per track

@@ -1,5 +1,5 @@
 """Performance observability: the analytic QTensor cost model, the
-device-timed dispatch spans, and the bench-history regression gate.
+engine's phase spans, and the bench-history regression gate.
 
 The load-bearing guarantees:
 
@@ -7,13 +7,12 @@ The load-bearing guarantees:
     ``qtensor.storage_summary`` of the realized packed blocks, to the
     byte, for every width x group size (qmm weights and paged KV
     pools).  The roofline is an accounting, not an estimate.
-  * ZERO-GRAPH-IMPACT — a perf-instrumented engine compiles the exact
-    same decode/prefill computation as an uninstrumented one (all
-    timing is host-side around the audited syncs), and perf-off pays
-    nothing.
-  * the merged device-timing track still passes the Chrome-trace
-    nesting validator, and trajectory files survive corrupt/missing
-    states.
+  * ZERO-GRAPH-IMPACT — a traced engine compiles the exact same
+    decode/prefill computation as an uninstrumented one (all phase
+    timing is host-side around the audited syncs).
+  * the merged trace (engine phases + request tracks) still passes the
+    Chrome-trace nesting validator, and trajectory files survive
+    corrupt/missing states.
 """
 import dataclasses
 import json
@@ -29,14 +28,15 @@ from repro.kvcache.paged import PagedKVConfig, init_paged_kv
 from repro.models import init_params
 from repro.obs import ObsConfig, Tracer, validate_chrome_trace
 from repro.obs.perf import (
-    DispatchTimer, attribute, check_regression, format_table,
+    attribute, check_regression, format_table,
     grouped_qmm_cost, grouped_qmm_weight_bytes, kv_pool_bytes, load_history,
     metric_direction, qmm_cost, qmm_weight_bytes, roofline,
     site_costs_from_tree)
 from repro.obs.perf.history import append_run
-from repro.obs.trace import DEVICE_TID
+from repro.obs.trace import ENGINE_TID
 from repro.qtensor import is_qtensor, quantize, storage_summary
 from repro.serve import Engine, EngineConfig, quantize_params, trace_requests
+from repro.serve.metrics import EngineMetrics
 
 TRACE = [(0, 8, 5), (0, 12, 7), (3, 6, 4)]
 ECFG = dict(max_slots=2, max_len=64, max_new_tokens=16,
@@ -184,63 +184,35 @@ def test_roofline_and_attribution_consistency():
 
 
 # ---------------------------------------------------------------------------
-# device-timed dispatch spans
+# engine phase spans
 # ---------------------------------------------------------------------------
 
-def test_dispatch_timer_cadence_and_compile_split():
-    tr = Tracer(enabled=True)
-    timer = DispatchTimer(time_every=3)
-    for i in range(7):
-        timer.record("decode_burst", 0.01, tokens=4,
-                     compiled=(i == 0), tracer=tr)
-    s = timer.summary()["decode_burst"]
-    assert s["count"] == 7 and s["compiled"] == 1
-    assert s["sampled"] == 3                       # samples 0, 3, 6
-    assert abs(s["wall_s"] - 0.07) < 1e-12
-    assert abs(s["compile_s"] - 0.01) < 1e-12
-    assert abs(s["exec_s"] - 0.06) < 1e-12
-    dev = [e for e in tr.chrome_trace()["traceEvents"]
-           if e.get("tid") == DEVICE_TID and e.get("ph") == "X"]
-    assert len(dev) == 3
-    assert all(e["name"] == "device:decode_burst" for e in dev)
-    assert dev[0]["args"]["compiled"] is True
-
-
-def test_dispatch_timer_rejects_bad_cadence():
-    with pytest.raises(ValueError):
-        DispatchTimer(time_every=0)
-    with pytest.raises(ValueError):
-        ObsConfig(perf=True, time_every=0)
-
-
 def test_profiled_engine_device_track_validates():
-    """A full profiled serve: the merged trace (engine + request +
-    device tracks) passes the nesting validator and carries audited,
-    cadenced device spans consistent with the timer's aggregates."""
-    obs = ObsConfig(trace=True, device_metrics=True, perf=True,
-                    time_every=2, drain_every=2)
+    """A full profiled serve: the merged trace (engine phases + request
+    tracks) passes the nesting validator, and its phase events are the
+    spans the metrics booked."""
+    obs = ObsConfig(trace=True, device_metrics=True, drain_every=2)
     _, eng = _perf_engine(obs)
     finished, metrics = eng.run(trace_requests(eng.cfg, TRACE))
     assert len(finished) == len(TRACE)
     trace = eng.tracer.chrome_trace()
     assert validate_chrome_trace(trace) == []
-    dev = [e for e in trace["traceEvents"]
-           if e.get("tid") == DEVICE_TID and e.get("ph") == "X"]
-    names = {e["name"] for e in dev}
-    assert {"device:prefill_chunk", "device:decode_burst"} <= names
-    summ = eng.perf.summary()
-    # cadence: the device track carries every 2nd sample per kind
-    for kind in ("prefill_chunk", "decode_burst"):
-        st = summ[kind]
-        assert st["sampled"] == -(-st["count"] // 2), (kind, st)
-    # the device track mirrors walls the aggregator booked
-    total_us = sum(e["dur"] for e in dev)
-    total_s = sum(st["wall_s"] for st in summ.values())
-    assert total_us <= total_s * 1e6 + 1.0
+    phases = [e for e in trace["traceEvents"]
+              if e.get("tid") == ENGINE_TID and e.get("ph") == "X"
+              and e["name"].startswith("engine.")]
+    names = {e["name"] for e in phases}
+    assert {"engine.prefill_chunk", "engine.decode_burst",
+            "engine.drain"} <= names
+    assert metrics.phase_n == {n: sum(e["name"] == n for e in phases)
+                               for n in names}
+    table = metrics.phase_table()
     # decode tokens measured == engine bookkeeping
-    assert summ["decode_burst"]["tokens"] == metrics.decode_tokens
+    assert table["engine.decode_burst"]["tokens"] == metrics.decode_tokens
+    assert sum(e["args"]["tokens"] for e in phases
+               if e["name"] == "engine.decode_burst") == \
+        metrics.decode_tokens
     # drains were timed too (drain_every=2 cadence + final drain)
-    assert summ["drain"]["count"] >= 2
+    assert table["engine.drain"]["count"] >= 2
 
 
 def _decode_jaxpr_str(eng) -> str:
@@ -257,23 +229,26 @@ def _decode_jaxpr_str(eng) -> str:
 
 
 def test_perf_off_is_compile_identical():
-    """The timing instrumentation never touches the jit'd graphs: an
-    obs-off engine and a perf-on engine (trace + timing, counters off)
-    lower the IDENTICAL decode-step jaxpr — all timing is host-side
-    around the audited syncs."""
-    obs = ObsConfig(trace=True, device_metrics=False, perf=True)
+    """The phase instrumentation never touches the jit'd graphs: an
+    obs-off engine and a traced engine lower the IDENTICAL decode-step
+    jaxpr — all phase timing is host-side around the audited syncs."""
     _, eng_off = _perf_engine(None)
-    _, eng_on = _perf_engine(obs)
-    assert eng_on.perf is not None and eng_off.perf is None
+    _, eng_on = _perf_engine(ObsConfig(trace=True))
+    assert eng_on.tracer.enabled and not eng_off.tracer.enabled
     assert _decode_jaxpr_str(eng_on) == _decode_jaxpr_str(eng_off)
 
 
 def test_engine_without_perf_has_no_timer():
+    """Phase timing takes no switch: with tracing off a phase span still
+    books its wall and count, but writes no trace event."""
     _, eng = _perf_engine(None)
-    assert eng.perf is None
-    obs = ObsConfig(trace=True)
-    _, eng2 = _perf_engine(obs)
-    assert eng2.perf is None                 # trace alone: no timing
+    assert not hasattr(eng, "perf") and not eng.tracer.enabled
+    m = EngineMetrics()
+    with eng.tracer.phase("engine.harvest", m) as ph:
+        pass
+    assert m.phase_n == {"engine.harvest": 1}
+    assert m.phase_s == {"engine.harvest": ph.s} and ph.s >= 0
+    assert eng.tracer.n_events == 0
 
 
 # ---------------------------------------------------------------------------
