@@ -27,7 +27,7 @@ from repro.kernels.fake_quant import (
 from repro.kernels.grouped_qmm import grouped_qmm_pallas
 from repro.kernels.int8_matmul import int8_matmul_pallas
 from repro.kernels.paged_attention import paged_attention_pallas
-from repro.kernels.qmm import qmm_groups_pallas, qmm_pallas
+from repro.kernels.qmm import MAX_GROUP, qmm_groups_pallas, qmm_pallas
 from repro.qtensor import PACKED_BITS, packed_size
 
 M, D, FF = 8, 2048, 8192                 # decode rows, d_model, d_ff
@@ -79,14 +79,34 @@ def _payload_dtype(bits):
     return jnp.uint8 if bits in PACKED_BITS else jnp.int8
 
 
+# (K, N) of the widest matrices the bench configurations serve: the heads
+# of internlm2_1_8b (92 544, which no chosen block width divides) and
+# minitron_4b, and their down projections (K past MAX_GROUP, so grouped
+# only)
+WIDEST = [(2048, 92544), (3072, 256000), (8192, 2048), (9216, 3072)]
+
+
+def _compile_qmm(chip_compile, m, k, n, bits, group):
+    n_groups = k // group if group else 1
+    return chip_compile(
+        lambda x, w, xs, ws: qmm_pallas(x, w, xs, ws, bits=bits, k=k),
+        ((m, k), jnp.int8), ((packed_size(k, bits), n), _payload_dtype(bits)),
+        ((m, 1), jnp.float32), ((n_groups, n), jnp.float32))
+
+
 @pytest.mark.parametrize("group", [GROUP, None], ids=["g128", "per_channel"])
 @pytest.mark.parametrize("bits", [8, 6, 4, 3])
 def test_qmm_compiles(chip_compile, bits, group):
-    n_groups = D // group if group else 1
-    chip_compile(
-        lambda x, w, xs, ws: qmm_pallas(x, w, xs, ws, bits=bits, k=D),
-        ((M, D), jnp.int8), ((packed_size(D, bits), FF), _payload_dtype(bits)),
-        ((M, 1), jnp.float32), ((n_groups, FF), jnp.float32))
+    """At the decode batch above, then with the tiles qmm_pallas chooses
+    at the widest served shapes, at prefill (M=1) and decode (M=64) rows:
+    a tile past the chip's VMEM shows here. No shape pads its payload."""
+    _compile_qmm(chip_compile, M, D, FF, bits, group)
+    for k, n in WIDEST:
+        if not group and k > MAX_GROUP:
+            continue
+        for m in (1, 64):
+            hlo = _compile_qmm(chip_compile, m, k, n, bits, group).as_text()
+            assert " pad(" not in hlo
 
 
 @pytest.mark.parametrize("bits", [8, 4])

@@ -136,10 +136,28 @@ def test_qmm_ref_equals_dense_dequant_matmul(bits, seed, gs):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-4)
 
 
+SMALL_TILES = dict(bm=16, bn=32)
+
+
 @pytest.mark.parametrize("bits", ALL_BITS)
-@pytest.mark.parametrize("m,k,n,gs", [(8, 32, 16, None), (24, 64, 48, 16),
-                                      (5, 48, 33, 12)])
-def test_qmm_pallas_matches_ref(rng, bits, m, k, n, gs):
+@pytest.mark.parametrize("m,k,n,gs,tiles", [
+    pytest.param(8, 32, 16, None, SMALL_TILES, id="8-32-16-None"),
+    pytest.param(24, 64, 48, 16, SMALL_TILES, id="24-64-48-16"),
+    pytest.param(5, 48, 33, 12, SMALL_TILES, id="5-48-33-12"),
+    # the tiles qmm_pallas chooses itself, at the engine's prefill (M=1)
+    # and decode (M=64) rows: every group in one K step
+    pytest.param(1, 512, 384, 128, {}, id="prefill-folded"),
+    pytest.param(64, 512, 384, 128, {}, id="decode-folded"),
+    # one and two groups per K step
+    pytest.param(64, 512, 384, 128, dict(bk=128), id="decode-gps1"),
+    pytest.param(1, 512, 384, 128, dict(bk=256), id="prefill-gps2"),
+    # N no chosen width divides: a partial last block (bn = 1024)
+    pytest.param(1, 256, 1300, 128, {}, id="prefill-partial-n"),
+    pytest.param(64, 256, 1300, 128, {}, id="decode-partial-n"),
+    # per-channel weights (one group of K rows)
+    pytest.param(64, 256, 384, None, {}, id="decode-per-channel"),
+])
+def test_qmm_pallas_matches_ref(rng, bits, m, k, n, gs, tiles):
     x = rng.normal(size=(m, k)).astype(np.float32)
     w = rng.normal(size=(k, n)).astype(np.float32)
     xq, xs = _rowquant(x)
@@ -148,9 +166,88 @@ def test_qmm_pallas_matches_ref(rng, bits, m, k, n, gs):
     g = wq.scale.shape[0]
     got = qmm_pallas(jnp.asarray(xq), wq.data, jnp.asarray(xs),
                      wq.scale.reshape(g, n), bits=bits, k=k,
-                     bm=16, bn=32, interpret=True)
+                     interpret=True, **tiles)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("bits", ALL_BITS)
+@pytest.mark.parametrize("m", [1, 64])
+@pytest.mark.parametrize("k,n,gs,tiles", [
+    pytest.param(512, 384, 128, dict(bk=256), id="gps2"),
+    pytest.param(512, 384, 128, {}, id="gps_all"),
+    pytest.param(256, 1300, 128, {}, id="gps_all-partial_n"),
+    pytest.param(256, 384, None, {}, id="per_channel"),
+])
+def test_qmm_folded_tiles_bit_identical_to_one_group_per_step(
+        rng, bits, m, k, n, gs, tiles):
+    """Folding several scale groups into one K step changes no bit of the
+    output: each group's int32 dot is folded into the fp32 accumulator in
+    the same order as with one group per step (``bk`` = group size), and
+    a partial last N block (N = 1300 against bn = 1024) matches one block
+    over the whole of N."""
+    x = rng.normal(size=(m, k)).astype(np.float32)
+    w = rng.normal(size=(k, n)).astype(np.float32)
+    xq, xs = _rowquant(x)
+    wq = qt.quantize(jnp.asarray(w), bits, group_size=gs)
+    g = wq.scale.shape[0]
+    args = (jnp.asarray(xq), wq.data, jnp.asarray(xs), wq.scale.reshape(g, n))
+    folded = qmm_pallas(*args, bits=bits, k=k, interpret=True, **tiles)
+    one_group = qmm_pallas(*args, bits=bits, k=k, bk=k // g, bn=n,
+                           interpret=True)
+    np.testing.assert_array_equal(np.asarray(folded), np.asarray(one_group))
+
+
+QMM_CONFIGS = {"internlm2_1_8b": 5_000, "minitron_4b": 10_000}
+
+
+def _served_qmm_shapes(cfg):
+    """(K, N, bits) of every matrix a bench configuration serves (its
+    layers' projections and the head), from its JSON file."""
+    d, ff = cfg["hidden_size"], cfg["intermediate_size"]
+    q = cfg["num_attention_heads"] * cfg["head_dim"]
+    kv = cfg["num_key_value_heads"] * cfg["head_dim"]
+    leaves = {"attn/wq": (d, q), "attn/wk": (d, kv), "attn/wv": (d, kv),
+              "attn/wo": (q, d), "mlp/w_up": (d, ff), "mlp/w_down": (ff, d),
+              "mlp/w_gate": (d, ff)}
+    out = []
+    for name, bits in cfg["allocation"]["weight_bits"].items():
+        if name == "head":
+            out.append((d, -(-cfg["vocab_size"] // 16) * 16, bits))
+        else:
+            out.append((*leaves[name.split("/", 2)[2]], bits))
+    return out
+
+
+@pytest.mark.parametrize("m", [1, 64])
+@pytest.mark.parametrize("name", sorted(QMM_CONFIGS))
+def test_qmm_tile_choice_for_served_shapes(name, m):
+    """The tiles qmm_pallas chooses for every matrix a bench configuration
+    serves, at prefill (M=1) and decode (M=64) rows: legal TPU blocks
+    (8-bit rows a multiple of 32 or the whole dimension, lanes a multiple
+    of 128 or the whole dimension, whole scale groups dividing K), VMEM
+    under the budget, and a whole weight pass in a few thousand grid
+    steps (one 128-row group per step took ~52 k / ~104 k)."""
+    from repro.kernels.qmm import VMEM_BUDGET, choose_tiles, vmem_bytes
+    path = os.path.join(os.path.dirname(__file__), "..", "bench", "configs",
+                        f"{name}.json")
+    with open(path) as f:
+        cfg = json.load(f)
+    group = cfg["engine"]["group_size"]
+    shapes = _served_qmm_shapes(cfg)
+    assert len(shapes) == cfg["num_hidden_layers"] * (
+        7 if cfg["hidden_act"] == "silu" else 6) + 1
+    steps = 0
+    for k, n, bits in shapes:
+        bm, bk, bn = choose_tiles(m, k, n, bits, group)
+        assert bm == m or bm % 32 == 0
+        assert bn == n or bn % 128 == 0
+        assert bk % group == 0 and k % bk == 0
+        assert bk == k or (bk % 128 == 0
+                           and qt.packed_size(bk, bits) % 32 == 0)
+        assert vmem_bytes(bm, bk, bn, bits, group) <= VMEM_BUDGET
+        steps += -(-m // bm) * -(-n // bn) * (k // bk)
+    assert steps <= QMM_CONFIGS[name]
 
 
 @pytest.mark.parametrize("bits", ALL_BITS)
